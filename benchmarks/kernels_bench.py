@@ -235,7 +235,7 @@ def main(argv=(), print_fn=print):
     public = sorted(
         name for name, f in inspect.getmembers(ops, inspect.isfunction)
         if f.__module__ == "repro.kernels.ops"
-        and not name.startswith("_") and name != "set_interpret")
+        and not name.startswith("_"))
     specs = _build_specs()
 
     rows = []

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config, smoke_variant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serve import (Engine, EngineConfig, FaultPlan, HealthConfig,
                          Request, ServeCluster, Telemetry)
@@ -502,6 +503,7 @@ def main():
                     "gauges, TTFT/TPOT/e2e histogram percentiles, "
                     "per-replica breakdown) as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.batch is None:
         args.batch = 4 if args.dispatch_sweep else 16
 

@@ -23,6 +23,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config, smoke_variant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serve import (Engine, EngineConfig, FaultPlan, Request,
                          RequestQueue, ServeCluster, Telemetry)
@@ -82,6 +83,7 @@ def main():
                     help="write a Chrome trace_event span timeline "
                     "(open in Perfetto / chrome://tracing)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_variant(get_config(args.arch)).replace(mtp_depth=0)
     model = build_model(cfg)

@@ -19,7 +19,8 @@ the Pallas TPU guide:
   KC003  block minor dim not a multiple of the 128-lane tile (and not
          the full array extent)
   KC004  block second-minor dim not sublane-aligned for the dtype
-         (8 f32 / 16 bf16 / 32 int8; 1 and full-extent are fine)
+         (8 f32 / 16 bf16 / 32 int8) nor the full array extent — a
+         width-1 block of a taller array is refused too
   KC005  VMEM scratch lane-misaligned (minor % 128) or a size-1 VMEM
          scratch that belongs in SMEM
   KC006  scalar-prefetch operand not SMEM-compatible (non-integer, or
@@ -60,7 +61,7 @@ _INDEX_MAP_OK = {"add", "sub", "mul", "div", "rem", "floordiv", "max",
                  "squeeze", "reshape", "broadcast_in_dim", "get",
                  "dynamic_slice", "slice", "gather", "concatenate",
                  "lt", "le", "gt", "ge", "eq", "ne", "and", "or", "not",
-                 "xor", "stop_gradient", "pjit", "clamp"}
+                 "xor", "stop_gradient", "jit", "clamp"}
 
 # host-side callback prims must never appear inside a kernel either
 _CALLBACKS = {"pure_callback", "io_callback", "debug_callback", "callback"}
@@ -158,16 +159,24 @@ def _check_body(where: str, kernel_jaxpr) -> List[Finding]:
     return out
 
 
+def _block_dim(d) -> int:
+    """Extent of one ``BlockMapping.block_shape`` entry: an int, a
+    ``Blocked``/``Element`` dim (its ``block_size``), or a squeezed
+    dim (1)."""
+    if isinstance(d, int):
+        return d
+    return getattr(d, "block_size", 1)
+
+
 def _check_blocks(where: str, grid_mapping) -> List[Finding]:
     out: List[Finding] = []
     for i, bm in enumerate(grid_mapping.block_mappings):
-        origin = getattr(bm, "origin", f"operand{i}")
-        block = [d for d in (bm.block_shape or ()) if isinstance(d, int)]
-        asd = getattr(bm, "array_shape_dtype", None)
-        if asd is None or len(block) < 2:
+        origin = bm.origin
+        block = [_block_dim(d) for d in bm.block_shape]
+        if len(block) < 2:
             continue
-        arr_shape = asd.shape
-        dt = jnp.dtype(asd.dtype)
+        arr_shape = bm.array_aval.shape
+        dt = jnp.dtype(bm.array_aval.dtype)
         minor, arr_minor = block[-1], arr_shape[-1]
         if minor % _LANE != 0 and minor != arr_minor:
             out.append(Finding(
@@ -179,12 +188,12 @@ def _check_blocks(where: str, grid_mapping) -> List[Finding]:
                 "block the full extent"))
         sub = _SUBLANE.get(dt.itemsize, 8)
         smin, arr_smin = block[-2], arr_shape[-2]
-        if smin != 1 and smin % sub != 0 and smin != arr_smin:
+        if smin % sub != 0 and smin != arr_smin:
             out.append(Finding(
                 "KC004", where, f"{origin}:block{tuple(block)}",
                 f"block second-minor dim {smin} of {origin} (array "
                 f"{tuple(arr_shape)} {dt.name}) is not {sub}-sublane "
-                f"aligned (nor 1, nor the full extent {arr_smin})",
+                f"aligned nor the full extent {arr_smin}",
                 f"round the second-minor block dim up to a multiple of "
                 f"{sub} for {dt.name}"))
     return out
@@ -399,7 +408,7 @@ def public_ops() -> List[str]:
     return sorted(
         name for name, f in inspect.getmembers(ops, inspect.isfunction)
         if f.__module__ == "repro.kernels.ops"
-        and not name.startswith("_") and name != "set_interpret")
+        and not name.startswith("_"))
 
 
 def kernel_spec_ops() -> List[str]:

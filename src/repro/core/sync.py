@@ -87,7 +87,7 @@ def layered_rsag_sync(grads, topo: Topology, mesh_axis_names, manual,
                 g = jax.lax.pmean(g, fast, axis_index_groups=p2)
         if slow:
             orig_shape = g.shape
-            n = sharding.axis_size(slow)
+            n = jax.lax.axis_size(slow)
             flat = g.reshape(-1)
             pad = (-flat.size) % n
             if pad:

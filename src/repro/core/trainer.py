@@ -198,10 +198,10 @@ def make_shardmap_step(model, tcfg: TrainerConfig, lr_fn, mesh):
         _, metrics_abs = jax.eval_shape(model.loss, state["params"], batch)
         out_specs = (state_specs,
                      (P(), jax.tree.map(lambda _: P(), metrics_abs)))
-        f = sharding.shard_map(wrapped, mesh,
-                               in_specs=(state_specs, bspecs),
-                               out_specs=out_specs,
-                               axis_names=set(manual), check=False)
+        f = jax.shard_map(wrapped, mesh=mesh,
+                          in_specs=(state_specs, bspecs),
+                          out_specs=out_specs,
+                          axis_names=set(manual), check_vma=False)
         return f(state, batch)
 
     return step_fn

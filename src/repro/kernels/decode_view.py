@@ -82,7 +82,7 @@ def _view_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 
 def decode_view_attend_bhd(q, k, v, pos, *, scale, window=0, block_kv=128,
-                           interpret=True):
+                           interpret):
     """q (B,H,hd); k,v (B,S,KV,hd) per-row contiguous views (slot j =
     logical position j); pos (B,) int32 per-row query positions.
     hd % 128 == 0, S % block_kv == 0.  ``scale`` is passed explicitly so

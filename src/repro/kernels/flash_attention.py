@@ -56,13 +56,15 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         mask &= kpos > qpos - window
     logits = jnp.where(mask, logits, NEG_INF)
 
-    m_prev = m_scr[...]                          # (bq, 1)
+    # m/l scratches are lane-padded (bq, 128) with every lane equal
+    # (see decode_view): per-row consumers slice lane 0
+    m_prev = m_scr[...]
     l_prev = l_scr[...]
     m_new = jnp.maximum(m_prev, logits.max(-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)
+    p = jnp.exp(logits - m_new[:, :1])
     l_new = l_prev * alpha + p.sum(-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+    acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
         p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_scr[...] = m_new
@@ -70,13 +72,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(kb == n_kv_blocks - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...][:, :1], 1e-30)
+                       ).astype(o_ref.dtype)
 
 
 def flash_attention_bhsd(q, k, v, *, causal=True, window=0,
                          block_q=128, block_kv=128, valid_len=None,
-                         interpret=True):
+                         interpret):
     """q: (B, H, Sq, hd); k, v: (B, KV, Sk, hd); hd multiple of 128,
     Sq % block_q == 0, Sk % block_kv == 0.  Returns (B, H, Sq, hd)."""
     b, h, sq, hd = q.shape
@@ -107,8 +109,8 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0,
                                lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, hd), q.dtype),
         scratch_shapes=[
-            _scratch((block_q, 1)),
-            _scratch((block_q, 1)),
+            _scratch((block_q, 128)),
+            _scratch((block_q, 128)),
             _scratch((block_q, hd)),
         ],
         interpret=interpret,
@@ -116,8 +118,5 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0,
 
 
 def _scratch(shape):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:
-        return pl.MemorySpace.ANY(shape, jnp.float32)  # pragma: no cover
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.VMEM(shape, jnp.float32)

@@ -48,22 +48,24 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     logits = jnp.where(kpos < len_ref[0, 0], logits, NEG_INF)
     logits = logits.reshape(h, logits.shape[-1])         # (H, bk)
 
+    # m/l scratches are lane-padded (rows, 128) with every lane equal
+    # (see decode_view): per-row consumers slice lane 0
     m_prev, l_prev = m_scr[...], l_scr[...]
     m_new = jnp.maximum(m_prev, logits.max(-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)                          # (H, bk)
+    p = jnp.exp(logits - m_new[:, :1])                   # (H, bk)
     l_scr[...] = l_prev * alpha + p.sum(-1, keepdims=True)
     pv = jax.lax.dot_general(
         p.reshape(kv_heads, group, -1), vt,
         (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)              # (KV, G, hd)
-    acc_scr[...] = acc_scr[...] * alpha + pv.reshape(h, hd)
+    acc_scr[...] = acc_scr[...] * alpha[:, :1] + pv.reshape(h, hd)
     m_scr[...] = m_new
 
     @pl.when(kb == n_kv_blocks - 1)
     def _fin():
-        o_ref[0] = (acc_scr[...]
-                    / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...][:, :1], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -107,28 +109,28 @@ def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     logits = jnp.where(valid, logits, NEG_INF)
     logits = logits.reshape(chunk * h, logits.shape[-1])   # (C*H, bs)
 
-    m_prev, l_prev = m_scr[...], l_scr[...]
+    m_prev, l_prev = m_scr[...], l_scr[...]             # (C*H, 128)
     m_new = jnp.maximum(m_prev, logits.max(-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)
+    p = jnp.exp(logits - m_new[:, :1])
     l_scr[...] = l_prev * alpha + p.sum(-1, keepdims=True)
     pv = jax.lax.dot_general(
         p.reshape(kv_heads, chunk * group, -1), vt,
         (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)                # (KV, C*G, hd)
-    acc_scr[...] = acc_scr[...] * alpha + pv.reshape(chunk * h, hd)
+    acc_scr[...] = acc_scr[...] * alpha[:, :1] + pv.reshape(chunk * h, hd)
     m_scr[...] = m_new
 
     @pl.when(i == n_blocks - 1)
     def _fin():
-        o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)  # (C*H, hd)
+        o = acc_scr[...] / jnp.maximum(l_scr[...][:, :1], 1e-30)
         o_ref[0] = (o.reshape(kv_heads, chunk, group, hd)
                     .swapaxes(0, 1)
                     .reshape(chunk, h, hd)).astype(o_ref.dtype)
 
 
 def flash_decode_paged_bhd(q, k_pool, v_pool, block_tables, pos, *,
-                           window=0, interpret=True):
+                           window=0, interpret):
     """Paged decode/prefill-chunk attention (the repro.serve hot loop).
 
     q (B,C,H,hd) — C query tokens per row (C=1 batched decode, C>1 a
@@ -164,7 +166,7 @@ def flash_decode_paged_bhd(q, k_pool, v_pool, block_tables, pos, *,
         ],
         out_specs=pl.BlockSpec((1, c, h, hd),
                                lambda bi, ki, bt, ps: (bi, 0, 0, 0)),
-        scratch_shapes=[_scratch((c * h, 1)), _scratch((c * h, 1)),
+        scratch_shapes=[_scratch((c * h, 128)), _scratch((c * h, 128)),
                         _scratch((c * h, hd))],
     )
     return pl.pallas_call(
@@ -176,7 +178,7 @@ def flash_decode_paged_bhd(q, k_pool, v_pool, block_tables, pos, *,
       jnp.asarray(pos, jnp.int32).reshape(b), q, k_pool, v_pool)
 
 
-def flash_decode_bhd(q, k, v, length, *, block_kv=512, interpret=True):
+def flash_decode_bhd(q, k, v, length, *, block_kv=512, interpret):
     """q (B,H,hd); k,v (B,S,KV,hd); length scalar int32 (#valid slots).
     hd % 128 == 0, S % block_kv == 0.  Returns (B,H,hd)."""
     b, h, hd = q.shape
@@ -199,7 +201,7 @@ def flash_decode_bhd(q, k, v, length, *, block_kv=512, interpret=True):
         ],
         out_specs=pl.BlockSpec((1, h, hd), lambda bi, ki: (bi, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
-        scratch_shapes=[_scratch((h, 1)), _scratch((h, 1)),
+        scratch_shapes=[_scratch((h, 128)), _scratch((h, 128)),
                         _scratch((h, hd))],
         interpret=interpret,
     )(len_arr, q, k, v)

@@ -41,7 +41,7 @@ def _kernel(w_ref, m_ref, g_ref, s_ref, w_out, m_out, *, momentum,
 
 
 def fused_sgd_update_2d(w, m, g, scalars, *, momentum, weight_decay,
-                        nesterov, interpret=True):
+                        nesterov, interpret):
     """w,m,g: (R, 128) with R % BLOCK_ROWS == 0; scalars: (1,2) f32
     [lr, trust]."""
     rows = w.shape[0]

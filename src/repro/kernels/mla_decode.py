@@ -102,7 +102,7 @@ def _mla_paged_body(bt_ref, pos_ref, *args, **kwargs):
 
 
 def mla_views_attend(q_lat, q_rope, ckv, kr, pos, *, scale, block=128,
-                     interpret=True):
+                     interpret):
     """q_lat (B,C,H,r), q_rope (B,C,H,rd); ckv (B,S,r), kr (B,S,rd)
     per-row contiguous latent views (slot j = position j); pos (B,).
     r % 128 == 0, rd % 128 == 0, S % block == 0.  Returns (B,C,H,r).
@@ -139,7 +139,7 @@ def mla_views_attend(q_lat, q_rope, ckv, kr, pos, *, scale, block=128,
 
 
 def mla_paged_attend(q_lat, q_rope, ckv_pool, kr_pool, block_tables, pos,
-                     *, scale, interpret=True):
+                     *, scale, interpret):
     """q_lat (B,C,H,r), q_rope (B,C,H,rd); pools (nb, bs, r)/(nb, bs, rd)
     shared across sequences; block_tables (B, n_blocks_per_seq) with
     trash block 0 backing unassigned entries; pos (B,) position of each

@@ -58,7 +58,7 @@ def _smem_scalar(dtype=jnp.float32):
 
 
 def _masked_block(lg_ref, kb, *, vocab, block_v):
-    vals = lg_ref[...].astype(jnp.float32)                  # (1, bv)
+    vals = lg_ref[0].astype(jnp.float32)                    # (1, bv)
     cols = kb * block_v + jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
     return jnp.where(cols < vocab, vals, -jnp.inf), cols
 
@@ -69,6 +69,12 @@ def _stream_argmax(score, cols, best_scr, idx_scr, *, vocab):
     take = m > best_scr[0, 0]
     idx_scr[0, 0] = jnp.where(take, j, idx_scr[0, 0])
     best_scr[0, 0] = jnp.where(take, m, best_scr[0, 0])
+
+
+def _emit(o_ref, idx_scr):
+    # the (1, 1, 1) output tile takes a vector store: broadcast the
+    # scalar-memory index instead of storing a scalar into VMEM
+    o_ref[...] = jnp.full(o_ref.shape, idx_scr[0, 0], jnp.int32)
 
 
 def _greedy_kernel(lg_ref, o_ref, best_scr, idx_scr, *, vocab, block_v,
@@ -85,7 +91,7 @@ def _greedy_kernel(lg_ref, o_ref, best_scr, idx_scr, *, vocab, block_v,
 
     @pl.when(kb == n_blocks - 1)
     def _fin():
-        o_ref[0, 0] = idx_scr[0, 0]
+        _emit(o_ref, idx_scr)
 
 
 def _gumbel_kernel(lg_ref, g_ref, o_ref, best_scr, idx_scr, *, vocab,
@@ -98,13 +104,13 @@ def _gumbel_kernel(lg_ref, g_ref, o_ref, best_scr, idx_scr, *, vocab,
         idx_scr[0, 0] = 0
 
     vals, cols = _masked_block(lg_ref, kb, vocab=vocab, block_v=block_v)
-    g = g_ref[...].astype(jnp.float32)
+    g = g_ref[0].astype(jnp.float32)
     score = jnp.where(cols < vocab, g + vals / temperature, -jnp.inf)
     _stream_argmax(score, cols, best_scr, idx_scr, vocab=vocab)
 
     @pl.when(kb == n_blocks - 1)
     def _fin():
-        o_ref[0, 0] = idx_scr[0, 0]
+        _emit(o_ref, idx_scr)
 
 
 def _topk_gumbel_kernel(lg_ref, g_ref, o_ref, topk_scr, kth_scr, best_scr,
@@ -148,21 +154,35 @@ def _topk_gumbel_kernel(lg_ref, g_ref, o_ref, topk_scr, kth_scr, best_scr,
     @pl.when(ph == 1)
     def _phase1():
         keep = (vals >= kth_scr[0, 0]) & (cols < vocab)
-        g = g_ref[...].astype(jnp.float32)
+        g = g_ref[0].astype(jnp.float32)
         score = jnp.where(keep, g + vals / temperature, -jnp.inf)
         _stream_argmax(score, cols, best_scr, idx_scr, vocab=vocab)
 
     @pl.when((ph == 1) & (kb == n_blocks - 1))
     def _fin():
-        o_ref[0, 0] = idx_scr[0, 0]
+        _emit(o_ref, idx_scr)
 
 
-def _pad_vocab(x, vp):
-    v = x.shape[-1]
-    return x if v == vp else jnp.pad(x, ((0, 0), (0, vp - v)))
+def _rows(x, vp):
+    """(B, V) -> (B, 1, Vp): pad V to the block multiple and give each
+    row its own unit sublane axis, so a (1, 1, block) tile's minor two
+    dims are (full extent, lane multiple) — Mosaic refuses a (1, block)
+    tile of a (B, Vp) array unless B == 1."""
+    b, v = x.shape
+    if v != vp:
+        x = jnp.pad(x, ((0, 0), (0, vp - v)))
+    return x.reshape(b, 1, vp)
 
 
-def greedy_sample(logits, *, interpret=True):
+def _vocab_spec(index_map):
+    return pl.BlockSpec((1, 1, _BLOCK_V), index_map)
+
+
+def _out_spec(index_map):
+    return pl.BlockSpec((1, 1, 1), index_map)
+
+
+def greedy_sample(logits, *, interpret):
     """Streaming per-row argmax; logits (B, V) -> (B,) int32, identical
     to ``jnp.argmax(logits, axis=-1)`` including first-occurrence
     ties."""
@@ -175,20 +195,20 @@ def greedy_sample(logits, *, interpret=True):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(b, nv),
-        in_specs=[pl.BlockSpec((1, _BLOCK_V), lambda bi, ki: (bi, ki))],
-        out_specs=pl.BlockSpec((1, 1), lambda bi, ki: (bi, 0)),
+        in_specs=[_vocab_spec(lambda bi, ki: (bi, 0, ki))],
+        out_specs=_out_spec(lambda bi, ki: (bi, 0, 0)),
         scratch_shapes=[_smem_scalar(), _smem_scalar(jnp.int32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, 1), jnp.int32),
         interpret=interpret,
-    )(_pad_vocab(logits, vp))
-    return out[:, 0]
+    )(_rows(logits, vp))
+    return out[:, 0, 0]
 
 
-def gumbel_sample(logits, gumbel, *, temperature, top_k=0, interpret=True):
+def gumbel_sample(logits, gumbel, *, temperature, top_k=0, interpret):
     """Fused temperature/top-k gumbel-max sampling; logits (B, V),
     gumbel (B, V) f32 noise drawn outside from the engine's per-row
     keys.  Token-identical to the jnp oracle
@@ -197,7 +217,7 @@ def gumbel_sample(logits, gumbel, *, temperature, top_k=0, interpret=True):
     b, v = logits.shape
     vp = -(-v // _BLOCK_V) * _BLOCK_V
     nv = vp // _BLOCK_V
-    lg, g = _pad_vocab(logits, vp), _pad_vocab(gumbel, vp)
+    lg, g = _rows(logits, vp), _rows(gumbel, vp)
     if top_k <= 0:
         kernel = functools.partial(
             _gumbel_kernel, vocab=v, block_v=_BLOCK_V, n_blocks=nv,
@@ -205,11 +225,9 @@ def gumbel_sample(logits, gumbel, *, temperature, top_k=0, interpret=True):
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
             grid=(b, nv),
-            in_specs=[
-                pl.BlockSpec((1, _BLOCK_V), lambda bi, ki: (bi, ki)),
-                pl.BlockSpec((1, _BLOCK_V), lambda bi, ki: (bi, ki)),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda bi, ki: (bi, 0)),
+            in_specs=[_vocab_spec(lambda bi, ki: (bi, 0, ki)),
+                      _vocab_spec(lambda bi, ki: (bi, 0, ki))],
+            out_specs=_out_spec(lambda bi, ki: (bi, 0, 0)),
             scratch_shapes=[_smem_scalar(), _smem_scalar(jnp.int32)],
         )
     else:
@@ -220,18 +238,16 @@ def gumbel_sample(logits, gumbel, *, temperature, top_k=0, interpret=True):
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
             grid=(b, 2, nv),
-            in_specs=[
-                pl.BlockSpec((1, _BLOCK_V), lambda bi, ph, ki: (bi, ki)),
-                pl.BlockSpec((1, _BLOCK_V), lambda bi, ph, ki: (bi, ki)),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda bi, ph, ki: (bi, 0)),
+            in_specs=[_vocab_spec(lambda bi, ph, ki: (bi, 0, ki)),
+                      _vocab_spec(lambda bi, ph, ki: (bi, 0, ki))],
+            out_specs=_out_spec(lambda bi, ph, ki: (bi, 0, 0)),
             scratch_shapes=[_scratch((1, kpad)), _smem_scalar(),
                             _smem_scalar(), _smem_scalar(jnp.int32)],
         )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, 1), jnp.int32),
         interpret=interpret,
     )(lg, g)
-    return out[:, 0]
+    return out[:, 0, 0]

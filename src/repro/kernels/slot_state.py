@@ -41,24 +41,33 @@ def _gather_kernel(slots_ref, fresh_ref, pool_ref, o_ref):
     o_ref[...] = jnp.where(fresh_ref[b] != 0, jnp.zeros_like(row), row)
 
 
-def slot_gather_rows(pool, slots, fresh, *, interpret=True):
+def _row_spec(rows, index_map):
+    # one pool row per grid step, viewed as (1, F/128, 128): the minor
+    # two block dims then span the whole row, which Mosaic accepts at
+    # any F/128 (a (1, F) block of an (S, F) array would need S == 1)
+    return pl.BlockSpec((1, rows, 128), index_map)
+
+
+def slot_gather_rows(pool, slots, fresh, *, interpret):
     """pool (S, F), slots (B,) int32, fresh (B,) int32 (nonzero → emit
     zeros).  F % 128 == 0.  Returns (B, F) in pool dtype."""
     from jax.experimental.pallas import tpu as pltpu
     s, f = pool.shape
     b = slots.shape[0]
+    r = f // 128
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[pl.BlockSpec((1, f), lambda bi, sl, fr: (sl[bi], 0))],
-        out_specs=pl.BlockSpec((1, f), lambda bi, sl, fr: (bi, 0)),
+        in_specs=[_row_spec(r, lambda bi, sl, fr: (sl[bi], 0, 0))],
+        out_specs=_row_spec(r, lambda bi, sl, fr: (bi, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, f), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, r, 128), pool.dtype),
         interpret=interpret,
-    )(slots, fresh, pool)
+    )(slots, fresh, pool.reshape(s, r, 128))
+    return out.reshape(b, f)
 
 
 def _scatter_kernel(src_ref, has_ref, pool_ref, val_ref, o_ref):
@@ -66,7 +75,7 @@ def _scatter_kernel(src_ref, has_ref, pool_ref, val_ref, o_ref):
     o_ref[...] = jnp.where(has_ref[s] != 0, val_ref[...], pool_ref[...])
 
 
-def slot_scatter_rows(pool, slots, values, *, interpret=True):
+def slot_scatter_rows(pool, slots, values, *, interpret):
     """pool (S, F); slots (B,) int32 destination per batch row; values
     (B, F) (already in pool dtype).  F % 128 == 0.  Returns the updated
     (S, F) pool — semantics of ``pool.at[slots].set(values)`` given the
@@ -74,6 +83,7 @@ def slot_scatter_rows(pool, slots, values, *, interpret=True):
     from jax.experimental.pallas import tpu as pltpu
     s, f = pool.shape
     b = values.shape[0]
+    r = f // 128
     src = jnp.full((s,), -1, jnp.int32).at[slots].set(
         jnp.arange(b, dtype=jnp.int32))
     has = (src >= 0).astype(jnp.int32)
@@ -82,14 +92,15 @@ def slot_scatter_rows(pool, slots, values, *, interpret=True):
         num_scalar_prefetch=2,
         grid=(s,),
         in_specs=[
-            pl.BlockSpec((1, f), lambda si, sc, hs: (si, 0)),
-            pl.BlockSpec((1, f), lambda si, sc, hs: (sc[si], 0)),
+            _row_spec(r, lambda si, sc, hs: (si, 0, 0)),
+            _row_spec(r, lambda si, sc, hs: (sc[si], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, f), lambda si, sc, hs: (si, 0)),
+        out_specs=_row_spec(r, lambda si, sc, hs: (si, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _scatter_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, f), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, r, 128), pool.dtype),
         interpret=interpret,
-    )(src_c, has, pool, values)
+    )(src_c, has, pool.reshape(s, r, 128), values.reshape(b, r, 128))
+    return out.reshape(s, f)

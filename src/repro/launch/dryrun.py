@@ -76,10 +76,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
                    traceback=traceback.format_exc()[-3000:])
         return rec
 
-    xla_cost = compiled.cost_analysis()
-    if isinstance(xla_cost, list):     # JAX 0.4.x: one dict per device
-        xla_cost = xla_cost[0]
-    xla_cost = dict(xla_cost)
+    xla_cost = dict(compiled.cost_analysis())
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     n_pods = 2 if multi_pod else 1
@@ -108,11 +105,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
             "output_bytes": mem.output_size_in_bytes,
             "temp_bytes": mem.temp_size_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes,
-            # JAX 0.4.x CompiledMemoryStats has no peak field; args +
-            # temps is the usable upper-bound surrogate there
-            "peak_bytes": getattr(
-                mem, "peak_memory_in_bytes",
-                mem.argument_size_in_bytes + mem.temp_size_in_bytes),
+            "peak_bytes": mem.peak_memory_in_bytes,
         },
         collectives={k: v for k, v in coll.items()},
         model_flops=mf,

@@ -20,19 +20,10 @@ DCI_BW = 6.25e9                   # bytes/s per chip (inter-pod, ~25GB/s/host)
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions.
-
-    JAX >= 0.5 meshes default every axis to Explicit typing unless
-    ``axis_types`` says otherwise; this codebase wants Auto everywhere.
-    JAX 0.4.x has neither ``jax.sharding.AxisType`` nor the
-    ``axis_types`` kwarg (Auto is the only behaviour), so feature-detect
-    and omit the argument there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (``jax.make_mesh``
+    defaults to Explicit typing; this codebase partitions with GSPMD)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

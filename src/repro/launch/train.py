@@ -26,6 +26,7 @@ from repro.core import (TrainerConfig, Topology, make_finalize,
                         make_init_state, make_shardmap_step)
 from repro.data.pipeline import DataConfig, HostLoader, data_config_for
 from repro.launch import builders
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.model import build_model
 from repro.optim.sgd import OptimConfig
@@ -61,7 +62,11 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--loss-chunk", type=int, default=0,
+                    help="cross-entropy over sequence chunks of this "
+                         "length (bounds the (B, S, vocab) logits)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -74,6 +79,8 @@ def main(argv=None):
                           head_dim=max(args.d_model // heads, 16))
     if args.d_ff:
         cfg = cfg.replace(d_ff=args.d_ff)
+    if args.loss_chunk:
+        cfg = cfg.replace(loss_chunk=args.loss_chunk)
     model = build_model(cfg)
 
     # mesh
@@ -108,7 +115,7 @@ def main(argv=None):
         sync_mode=args.sync_mode,
         optim=OptimConfig(kind=args.optimizer),
         topology=Topology(intra_group_size=args.intra_group_size))
-    state = make_init_state(model, tcfg)(jax.random.key(args.seed))
+    state = jax.jit(make_init_state(model, tcfg))(jax.random.key(args.seed))
     n_params = sum(x.size for x in jax.tree.leaves(state["params"]))
     print(f"arch={cfg.name} params={n_params:,} sync={args.sync_mode} "
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}")
@@ -122,23 +129,30 @@ def main(argv=None):
     dcfg = data_config_for(cfg, shape, seed=args.seed)
     loader = HostLoader(dcfg, io_latency_s=args.io_latency)
 
-    step_fn = jax.jit(make_shardmap_step(model, tcfg, lr_fn, mesh),
-                      donate_argnums=0)
     finalize = jax.jit(make_finalize(model, tcfg, lr_fn))
 
-    t0 = time.time()
     tokens_per_step = args.batch * args.seq
+    losses = []
     try:
+        batch = {k: jnp.asarray(v) for k, v in next(loader).items()}
+        t0 = time.time()
+        step_fn = jax.jit(make_shardmap_step(model, tcfg, lr_fn, mesh),
+                          donate_argnums=0).lower(state, batch).compile()
+        compile_s = time.time() - t0
+        print(f"compiled train step in {compile_s:.1f}s (wall)")
+        t0 = time.time()
         for i in range(args.steps):
-            batch = {k: jnp.asarray(v) for k, v in next(loader).items()}
+            if i:
+                batch = {k: jnp.asarray(v) for k, v in next(loader).items()}
             state, (loss, metrics) = step_fn(state, batch)
+            losses.append(loss)
             if (i + 1) % args.log_every == 0 or i == 0:
                 loss_v = float(loss)
                 dt = time.time() - t0
                 tput = tokens_per_step * (i + 1) / dt
                 print(f"step {i+1:5d} loss {loss_v:.4f} "
                       f"lr {float(lr_fn(i)):.4f} "
-                      f"tok/s {tput:,.0f}")
+                      f"tok/s {tput:,.0f} (wall)")
             if args.ckpt_dir and args.ckpt_every \
                     and (i + 1) % args.ckpt_every == 0:
                 checkpoint.save(args.ckpt_dir, state, int(state["step"]))
@@ -147,8 +161,10 @@ def main(argv=None):
     state = finalize(state)
     if args.ckpt_dir:
         checkpoint.save(args.ckpt_dir, state, int(state["step"]))
-    print(f"done in {time.time()-t0:.1f}s; final loss {float(loss):.4f}")
-    return state
+    losses = [float(x) for x in losses]
+    print(f"done in {time.time()-t0:.1f}s (wall); final loss "
+          f"{losses[-1]:.4f}")
+    return {"state": state, "losses": losses, "compile_s": compile_s}
 
 
 if __name__ == "__main__":

@@ -154,7 +154,7 @@ def _moe_local(xt, router_w, w_gate, w_up, w_down, cfg, data_axis: str,
     t, d = xt.shape
     k = m.num_experts_per_tok
     e = m.num_experts
-    n_shards = sharding.axis_size(data_axis)
+    n_shards = jax.lax.axis_size(data_axis)
 
     logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
                         router_w.astype(jnp.float32))
@@ -233,14 +233,14 @@ def apply_moe_ep(params, x, cfg, mesh):
         # out_spec also trips the crash
         return y.reshape(xt.shape), aux[None]
 
-    f = sharding.shard_map(
-        body, mesh,
+    f = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(dp, None, None), P(),
                   P("data", None, model_axis),
                   P("data", None, model_axis),
                   P("data", model_axis, None)),
         out_specs=(P(dp, None, None), P(dp)),
-        axis_names=manual, check=True)
+        axis_names=set(manual), check_vma=True)
     y, aux = f(x.astype(jnp.float32),
                params["router"]["w"].astype(jnp.float32),
                params["experts"]["w_gate"].astype(jnp.float32),

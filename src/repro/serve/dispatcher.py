@@ -198,14 +198,20 @@ class ServeCluster:
         """``num_replicas`` engines over the visible devices: honest
         disjoint slices when the device count divides evenly (each slice
         is one fast-fabric group, served tensor-parallel at
-        tp=devices/replicas), round-robin shared single-device slices
-        otherwise (CPU smoke on a 1-device host)."""
+        tp=devices/replicas).  Otherwise replicas share single-device
+        slices round-robin — allowed only on the CPU backend (smoke runs
+        on a 1-device host); on an accelerator that would stack replicas
+        on one chip, so it raises."""
         devices = list(jax.devices()) if devices is None else list(devices)
         n = len(devices)
         if num_replicas <= n and n % num_replicas == 0:
             topo = Topology(intra_group_size=n // num_replicas)
             return cls(model, params, cfg, topology=topo, devices=devices,
                        **kw)
+        if devices[0].platform != "cpu":
+            raise ValueError(
+                f"{num_replicas} replicas cannot get disjoint slices of "
+                f"{n} {devices[0].platform} devices")
         slices = [(devices[i % n],) for i in range(num_replicas)]
         return cls(model, params, cfg, slices=slices, **kw)
 

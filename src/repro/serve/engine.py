@@ -118,6 +118,22 @@ class _EngineMetrics:
         self.latency = LatencyHists(registry, **labels)
 
 
+def traced_on_mesh(mesh: Optional[Mesh], fn):
+    """``fn`` traced with ``mesh`` as the context mesh, so the Pallas
+    wrappers in ``repro.kernels.ops`` run their kernels per shard of a
+    tensor-parallel replica (a compiled Mosaic kernel cannot be split
+    by the SPMD partitioner).  Identity for single-device replicas."""
+    if mesh is None:
+        return fn
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+
+    return traced
+
+
 # positional argnums of (cache, slot_buf) in paged_step /
 # paged_decode_loop — the device state donated (aliased in place)
 # across dispatches.  ``repro.analysis.hotpath_check`` lints traced
@@ -392,21 +408,22 @@ class Engine:
                 if self.mesh is not None
                 else ("dev", self.device.id) if self.device is not None
                 else None)
+        on_mesh = functools.partial(traced_on_mesh, self.mesh)
         self._step_fn = model.jit_cache.setdefault(
             ("paged_step", donate, skey, pkey),
-            jax.jit(functools.partial(model.paged_step, **sample_kw),
+            jax.jit(on_mesh(functools.partial(model.paged_step,
+                                              **sample_kw)),
                     donate_argnums=donate))
         self._loop_fn = (model.jit_cache.setdefault(
             ("paged_decode_loop", donate, skey, cfg.steps_per_dispatch,
              pkey),
-            jax.jit(functools.partial(model.paged_decode_loop,
-                                      num_steps=cfg.steps_per_dispatch,
-                                      **sample_kw),
-                    donate_argnums=donate))
+            jax.jit(on_mesh(functools.partial(
+                model.paged_decode_loop, num_steps=cfg.steps_per_dispatch,
+                **sample_kw)), donate_argnums=donate))
             if cfg.steps_per_dispatch > 1 else None)
         self._legacy_fn = (model.jit_cache.setdefault(
             ("paged_step_logits", (1,), pkey),
-            jax.jit(model.paged_step_logits, donate_argnums=(1,)))
+            jax.jit(on_mesh(model.paged_step_logits), donate_argnums=(1,)))
             if not cfg.fused else None)
         self._slot_buf = jnp.zeros((cfg.num_slots + 1,), jnp.int32)
         if self.mesh is not None:
@@ -461,46 +478,31 @@ class Engine:
                 if f is not None]
 
     @staticmethod
-    def _jit_cache_total(fns) -> Optional[int]:
-        """Sum of compiled-executable cache sizes across ``fns``; None
-        when the running JAX doesn't expose ``_cache_size`` (the metric
-        then stays 0 rather than guessing)."""
-        total, supported = 0, False
-        for f in fns:
-            try:
-                total += int(f._cache_size())
-                supported = True
-            except Exception:
-                pass
-        return total if supported else None
+    def _jit_cache_total(fns) -> int:
+        """Sum of compiled-executable cache sizes across ``fns``."""
+        return sum(int(f._cache_size()) for f in fns)
 
     _COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
                        "all-to-all", "collective-permute")
 
     def _note_tp_collectives(self) -> None:
-        """Best-effort: AOT-compile the smallest decode shape and count
-        the collective ops XLA's SPMD partitioner placed in it — the
+        """AOT-compile the smallest decode shape and count the
+        collective ops XLA's SPMD partitioner placed in it — the
         per-dispatch fast-fabric communication a TP replica pays.  The
-        extra compile happens at warmup (never mid-serving) and any
-        introspection failure leaves the gauge at 0."""
-        try:
-            rows = self.cfg.decode_buckets[-1]
-            meta = np.zeros((6, rows), np.int32)
-            meta[2:4] = -1
-            txt = self._step_fn.lower(
-                self.params, self.cache, self._slot_buf,
-                np.zeros((rows, 1), np.int32),
-                self.kv.table_array([None] * rows), meta).compile().as_text()
-            self._m.tp_collective_ops.set(sum(
-                len(re.findall(rf"\b{op}\(", txt))
-                for op in self._COLLECTIVE_OPS))
-        except Exception:
-            pass
+        extra compile happens at warmup (never mid-serving)."""
+        rows = self.cfg.decode_buckets[-1]
+        meta = np.zeros((6, rows), np.int32)
+        meta[2:4] = -1
+        txt = self._step_fn.lower(
+            self.params, self.cache, self._slot_buf,
+            np.zeros((rows, 1), np.int32),
+            self.kv.table_array([None] * rows), meta).compile().as_text()
+        self._m.tp_collective_ops.set(sum(
+            len(re.findall(rf"\b{op}\(", txt))
+            for op in self._COLLECTIVE_OPS))
 
     def _note_compiles(self) -> None:
         cur = self._jit_cache_total(self._jit_fns())
-        if cur is None:
-            return
         if self._jit_cache_seen is None:
             self._jit_cache_seen = cur
         elif cur > self._jit_cache_seen:

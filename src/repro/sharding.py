@@ -30,38 +30,6 @@ def active_mesh() -> Optional[Mesh]:
     return _ACTIVE_MESH
 
 
-def shard_map(f, mesh, *, in_specs, out_specs, axis_names, check=False):
-    """``jax.shard_map`` across JAX versions.
-
-    JAX >= 0.6 exposes ``jax.shard_map(..., axis_names=<manual axes>,
-    check_vma=...)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., auto=<auto axes>,
-    check_rep=...)``.  Same semantics, complementary axis-set argument.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=set(axis_names), check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # 0.4.x: a scan inside a *partial-auto* shard_map trips a fatal XLA
-    # check (hlo_sharding_util: sharding.IsManualSubgroup()), and every
-    # model here scans over layers.  Fold the auto axes into the manual
-    # set instead: inputs spec'd P() stay fully replicated over them, so
-    # compute is redundant across those shards but value-identical.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
-
-
-def axis_size(name):
-    """``jax.lax.axis_size`` (JAX >= 0.6) with a 0.4.x fallback via the
-    bound axis environment.  Only valid inside a shard_map/pmap region
-    where ``name`` is a manual axis."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    from jax._src import core as _core
-    return _core.get_axis_env().axis_size(name)
-
-
 def hint(x, *spec):
     """with_sharding_constraint if a mesh is active, else identity.
 

@@ -204,7 +204,8 @@ def test_kernel_pass_shipped_tree_clean_modulo_baseline():
     # no stale entries: every baselined deviation still exists
     assert baseline.stale(findings) == []
     # the serve decode hot path must NOT hide behind the baseline
-    hot = ("decode_view_attend", "mla_decode_views", "mla_decode_paged",
+    hot = ("decode_view_attend", "flash_decode_paged", "mla_decode_views",
+           "mla_decode_paged",
            "slot_gather", "slot_scatter", "sample_tokens")
     assert not [f for f in accepted
                 if f.where.split("/")[0] in hot], accepted

@@ -54,10 +54,7 @@ def test_xla_cost_analysis_undercounts_scans():
         return jax.lax.scan(lambda c, _: (c @ W, None), x, None,
                             length=10)[0]
     c = _compile(fs, (128, 128))
-    xla = c.cost_analysis()
-    if isinstance(xla, list):         # JAX 0.4.x: one dict per device
-        xla = xla[0]
-    xla = xla["flops"]
+    xla = c.cost_analysis()["flops"]
     ours = ha.account(c.as_text()).flops
     assert ours > 5 * xla     # 10x body count vs 1x
 

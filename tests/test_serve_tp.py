@@ -32,6 +32,8 @@ TESTS = os.path.join(ROOT, "tests")
 def _run(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # the child never reaches for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (SRC + os.pathsep + TESTS + os.pathsep
                          + env.get("PYTHONPATH", ""))
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -18,6 +18,8 @@ SRC = os.path.join(REPO, "src")
 def _run_module(args, devices=None, timeout=900):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    # the child never reaches for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     if devices:
         env["XLA_FLAGS"] = \
             f"--xla_force_host_platform_device_count={devices}"

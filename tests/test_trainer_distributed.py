@@ -14,6 +14,8 @@ SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 def _run(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    # the child never reaches for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, timeout=600)
