@@ -16,6 +16,7 @@ wall time) and p50/p99 request latency (arrival -> last token).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import os
@@ -135,28 +136,28 @@ def run_static(model, params, workload, batch_size, pad_to=16):
 
 
 def run_cluster(model, params, workload, ecfg, num_replicas,
-                trace_path=None, metrics_path=None):
+                trace_dir=None, metrics_path=None):
     """Tokens/sec at saturation: every request submitted at t=0, one
     Engine per fast-fabric device slice, real wall clock (replicas run
     concurrently — that concurrency is the thing being measured, so no
     simulated clock here).  Per-token traffic never leaves a slice; the
     dispatcher thread only fans out admissions and collects results."""
     cluster = ServeCluster.for_replicas(model, params, ecfg,
-                                        num_replicas=num_replicas,
-                                        trace=trace_path is not None)
+                                        num_replicas=num_replicas)
     cluster.warmup()                 # per-device compiles off the clock
     reqs = [Request(prompt=w["prompt"], max_new_tokens=w["max_new_tokens"])
             for w in workload]
+    profile = (cluster.telemetry.profile(trace_dir) if trace_dir
+               else contextlib.nullcontext())
     t0 = time.perf_counter()
-    with cluster:
+    with profile, cluster:
         for r in reqs:
             cluster.submit(r)
     wall = time.perf_counter() - t0
     results = cluster.results()
     assert len(results) == len(reqs)
-    if trace_path:
-        cluster.write_trace(trace_path)
-        print(f"wrote {trace_path}")
+    if trace_dir:
+        print(f"wrote a profiler trace under {trace_dir}")
     if metrics_path:
         cluster.write_metrics(metrics_path)
         print(f"wrote {metrics_path}")
@@ -492,12 +493,13 @@ def main():
                     help="write the result rows as JSON (CI uploads this "
                     "as a workflow artifact so the perf trajectory is "
                     "recoverable from CI history)")
-    ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="write a Chrome trace_event span timeline "
-                    "(open in Perfetto / chrome://tracing): per-replica "
-                    "host+device tracks and the dispatcher track.  "
-                    "Opt-in; applies to the --replicas and --steps "
-                    "(single-engine smoke) modes")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="profile the run into DIR: a JAX profiler trace "
+                    "with each replica worker's repro:engine.* spans, the "
+                    "repro:dispatcher.route spans and the device ops on "
+                    "one clock (open DIR's perfetto_trace.json.gz in "
+                    "Perfetto).  Opt-in; applies to the --replicas and "
+                    "--steps (single-engine smoke) modes")
     ap.add_argument("--metrics-json", default=None, metavar="PATH",
                     help="write the telemetry snapshot (counters, "
                     "gauges, TTFT/TPOT/e2e histogram percentiles, "
@@ -702,7 +704,7 @@ def main():
             # this mode exists to exercise trace/metrics export
             # end-to-end (2 replicas, depth N, real worker threads)
             emit(run_cluster(model, params, workload, ecfg, args.replicas,
-                             trace_path=args.trace,
+                             trace_dir=args.trace,
                              metrics_path=args.metrics_json))
             print("[smoke] solo baseline + scaling gate skipped")
             write_json()
@@ -710,7 +712,7 @@ def main():
         solo = run_cluster(model, params, workload, ecfg, 1)
         emit(solo)
         multi = run_cluster(model, params, workload, ecfg, args.replicas,
-                            trace_path=args.trace,
+                            trace_dir=args.trace,
                             metrics_path=args.metrics_json)
         emit(multi)
         scaling = multi["tok_per_s"] / solo["tok_per_s"]
@@ -727,13 +729,14 @@ def main():
         return
 
     if args.steps is not None:
-        tel = (Telemetry(trace=bool(args.trace))
-               if (args.trace or args.metrics_json) else None)
-        emit(run_continuous(model, params, workload, ecfg,
-                            max_steps=args.steps, telemetry=tel))
+        tel = Telemetry() if (args.trace or args.metrics_json) else None
+        profile = (tel.profile(args.trace) if args.trace
+                   else contextlib.nullcontext())
+        with profile:
+            emit(run_continuous(model, params, workload, ecfg,
+                                max_steps=args.steps, telemetry=tel))
         if args.trace:
-            tel.write_trace(args.trace)
-            print(f"wrote {args.trace}")
+            print(f"wrote a profiler trace under {args.trace}")
         if args.metrics_json:
             with open(args.metrics_json, "w") as f:
                 json.dump(tel.registry.snapshot(), f, indent=2,

@@ -15,6 +15,7 @@ below = two tp=4 engines).
         PYTHONPATH=src python -m examples.serve_lm --replicas 2
 """
 import argparse
+import contextlib
 import dataclasses
 import threading
 import time
@@ -79,9 +80,11 @@ def main():
     ap.add_argument("--metrics", action="store_true",
                     help="print the telemetry snapshot table on exit "
                     "(counters, gauges, TTFT/TPOT/e2e percentiles)")
-    ap.add_argument("--trace", default=None, metavar="PATH",
-                    help="write a Chrome trace_event span timeline "
-                    "(open in Perfetto / chrome://tracing)")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="profile the serving run into DIR: a JAX "
+                    "profiler trace with the engine's repro:* host spans "
+                    "and the device ops on one clock (open DIR's "
+                    "perfetto_trace.json.gz in Perfetto)")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -100,7 +103,7 @@ def main():
     ecfg = dataclasses.replace(
         ecfg, num_blocks=(ecfg.max_batch + ecfg.admission_lookahead)
         * ecfg.blocks_per_seq + 1)
-    telemetry = Telemetry(trace=args.trace is not None)
+    telemetry = Telemetry()
     plan = None
     if args.chaos_kill is not None:
         if args.replicas < 2:
@@ -134,9 +137,11 @@ def main():
         queue.close()
 
     producer = threading.Thread(target=client)
+    profile = (telemetry.profile(args.trace) if args.trace
+               else contextlib.nullcontext())
     t0 = time.perf_counter()
     producer.start()
-    with queue:
+    with profile, queue:
         results = server.run(request_queue=queue)
     producer.join()
     wall = time.perf_counter() - t0
@@ -171,8 +176,7 @@ def main():
     if args.metrics:
         _print_metrics(telemetry.registry.snapshot())
     if args.trace:
-        telemetry.write_trace(args.trace)
-        print(f"wrote {args.trace} (open in Perfetto / chrome://tracing)")
+        print(f"wrote a profiler trace under {args.trace}")
 
 
 if __name__ == "__main__":

@@ -77,6 +77,7 @@ def make_init_state(model, tcfg: TrainerConfig):
     return init_fn
 
 
+@jax.named_scope("lsgd.apply_pending")
 def _apply_pending(state, lr_fn, ocfg):
     """Deferred update of step t-1 (LSGD Alg. 3 line 10); no-op at step 0."""
     params, opt = state["params"], state["opt"]
@@ -93,7 +94,13 @@ def _algorithm(model, tcfg: TrainerConfig, lr_fn, sync_fn):
     """The step body, shared by both execution paths.  ``sync_fn`` maps the
     raw (local or global) gradient pytree to the fully-averaged one; in the
     pjit path it is identity (autodiff of the global-mean loss already
-    averages)."""
+    averages).
+
+    Each phase is a ``jax.named_scope``, so its ops carry the name in
+    their HLO ``op_name`` and in the device trace: ``lsgd.apply_pending``
+    (the deferred update), ``lsgd.fwd_bwd`` (loss and gradients),
+    ``lsgd.sync`` and ``lsgd.pending_write`` (LSGD) or ``lsgd.update``
+    (the eager update of the other modes)."""
     ocfg = tcfg.optim
 
     def step(state, batch):
@@ -103,23 +110,28 @@ def _algorithm(model, tcfg: TrainerConfig, lr_fn, sync_fn):
         else:
             params, opt = state["params"], state["opt"]
 
-        (loss, metrics), grads = jax.value_and_grad(
-            model.loss, has_aux=True)(params, batch)
-        gdt = jnp.dtype(tcfg.grad_dtype)
-        grads = jax.tree.map(lambda g: g.astype(gdt), grads)
+        with jax.named_scope("lsgd.fwd_bwd"):
+            (loss, metrics), grads = jax.value_and_grad(
+                model.loss, has_aux=True)(params, batch)
+            gdt = jnp.dtype(tcfg.grad_dtype)
+            grads = jax.tree.map(lambda g: g.astype(gdt), grads)
 
-        if tcfg.sync_mode == "lsgd_compressed":
-            grads, new_res = sync_fn(grads, state["residual"])
-            new_state["residual"] = new_res
-        else:
-            grads = sync_fn(grads)
+        with jax.named_scope("lsgd.sync"):
+            if tcfg.sync_mode == "lsgd_compressed":
+                grads, new_res = sync_fn(grads, state["residual"])
+                new_state["residual"] = new_res
+            else:
+                grads = sync_fn(grads)
 
         if tcfg.defer_update:
-            new_state["pending"] = jax.tree.map(
-                lambda g, old: g.astype(old.dtype), grads, state["pending"])
+            with jax.named_scope("lsgd.pending_write"):
+                new_state["pending"] = jax.tree.map(
+                    lambda g, old: g.astype(old.dtype), grads,
+                    state["pending"])
         else:
-            params, opt = apply_update(params, opt, grads,
-                                       lr_fn(state["step"]), ocfg)
+            with jax.named_scope("lsgd.update"):
+                params, opt = apply_update(params, opt, grads,
+                                           lr_fn(state["step"]), ocfg)
         new_state["params"] = params
         new_state["opt"] = opt
         new_state["step"] = state["step"] + 1

@@ -98,6 +98,10 @@ class HostLoader:
 
     ``io_latency_s`` models the per-batch disk/decode time the paper's
     workers spend loading JPEGs — the slack LSGD hides collectives in.
+
+    Counters, read by subtracting two readings: ``batches`` handed out,
+    ``wait_s`` the seconds ``__next__`` blocked on an empty queue (the
+    input stall the step saw).
     """
 
     def __init__(self, cfg: DataConfig, *, prefetch: int = 2,
@@ -108,7 +112,8 @@ class HostLoader:
         self.transform = transform
         self._q: "queue.Queue" = queue.Queue(maxsize=prefetch)
         self._stop = threading.Event()
-        self._step = 0
+        self.batches = 0
+        self.wait_s = 0.0
         self._thread = threading.Thread(target=self._worker, daemon=True)
         self._thread.start()
 
@@ -134,7 +139,14 @@ class HostLoader:
     def __next__(self) -> Dict[str, np.ndarray]:
         if self._stop.is_set():
             raise StopIteration
-        return self._q.get()
+        try:
+            batch = self._q.get_nowait()
+        except queue.Empty:
+            t = time.perf_counter()
+            batch = self._q.get()
+            self.wait_s += time.perf_counter() - t
+        self.batches += 1
+        return batch
 
     def close(self):
         """Idempotent, race-free shutdown.

@@ -116,5 +116,6 @@ def decode_view_attend_bhd(q, k, v, pos, *, scale, window=0, block_kv=128,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
+        name="decode_view_attend",
         interpret=interpret,
     )(jnp.asarray(pos, jnp.int32).reshape(b), q, k, v)

@@ -113,6 +113,7 @@ def flash_attention_bhsd(q, k, v, *, causal=True, window=0,
             _scratch((block_q, 128)),
             _scratch((block_q, hd)),
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
 

@@ -173,6 +173,7 @@ def flash_decode_paged_bhd(q, k_pool, v_pool, block_tables, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c, h, hd), q.dtype),
+        name="flash_decode_paged",
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(pos, jnp.int32).reshape(b), q, k_pool, v_pool)
@@ -203,5 +204,6 @@ def flash_decode_bhd(q, k, v, length, *, block_kv=512, interpret):
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
         scratch_shapes=[_scratch((h, 128)), _scratch((h, 128)),
                         _scratch((h, hd))],
+        name="flash_decode",
         interpret=interpret,
     )(len_arr, q, k, v)

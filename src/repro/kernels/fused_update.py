@@ -59,5 +59,6 @@ def fused_sgd_update_2d(w, m, g, scalars, *, momentum, weight_decay,
                    pl.BlockSpec((BLOCK_ROWS, LANE), blk)],
         out_shape=[jax.ShapeDtypeStruct(w.shape, w.dtype),
                    jax.ShapeDtypeStruct(m.shape, m.dtype)],
+        name="fused_sgd_update",
         interpret=interpret,
     )(w, m, g, scalars)

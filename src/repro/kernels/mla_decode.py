@@ -134,6 +134,7 @@ def mla_views_attend(q_lat, q_rope, ckv, kr, pos, *, scale, block=128,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c, h, r), q_lat.dtype),
+        name="mla_views_attend",
         interpret=interpret,
     )(jnp.asarray(pos, jnp.int32).reshape(b), q_lat, q_rope, ckv, kr)
 
@@ -174,6 +175,7 @@ def mla_paged_attend(q_lat, q_rope, ckv_pool, kr_pool, block_tables, pos,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c, h, r), q_lat.dtype),
+        name="mla_paged_attend",
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(pos, jnp.int32).reshape(b),

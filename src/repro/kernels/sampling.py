@@ -203,6 +203,7 @@ def greedy_sample(logits, *, interpret):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, 1), jnp.int32),
+        name="greedy_sample",
         interpret=interpret,
     )(_rows(logits, vp))
     return out[:, 0, 0]
@@ -219,6 +220,7 @@ def gumbel_sample(logits, gumbel, *, temperature, top_k=0, interpret):
     nv = vp // _BLOCK_V
     lg, g = _rows(logits, vp), _rows(gumbel, vp)
     if top_k <= 0:
+        name = "gumbel_sample"
         kernel = functools.partial(
             _gumbel_kernel, vocab=v, block_v=_BLOCK_V, n_blocks=nv,
             temperature=float(temperature))
@@ -232,6 +234,7 @@ def gumbel_sample(logits, gumbel, *, temperature, top_k=0, interpret):
         )
     else:
         kpad = -(-int(top_k) // 128) * 128        # lane-pad the top-k scratch
+        name = "topk_gumbel_sample"
         kernel = functools.partial(
             _topk_gumbel_kernel, vocab=v, block_v=_BLOCK_V, n_blocks=nv,
             k=int(top_k), temperature=float(temperature))
@@ -248,6 +251,7 @@ def gumbel_sample(logits, gumbel, *, temperature, top_k=0, interpret):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, 1), jnp.int32),
+        name=name,
         interpret=interpret,
     )(lg, g)
     return out[:, 0, 0]

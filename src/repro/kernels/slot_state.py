@@ -65,6 +65,7 @@ def slot_gather_rows(pool, slots, fresh, *, interpret):
         _gather_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, r, 128), pool.dtype),
+        name="slot_gather_rows",
         interpret=interpret,
     )(slots, fresh, pool.reshape(s, r, 128))
     return out.reshape(b, f)
@@ -101,6 +102,7 @@ def slot_scatter_rows(pool, slots, values, *, interpret):
         _scatter_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, r, 128), pool.dtype),
+        name="slot_scatter_rows",
         interpret=interpret,
     )(src_c, has, pool.reshape(s, r, 128), values.reshape(b, r, 128))
     return out.reshape(s, f)

@@ -70,5 +70,6 @@ def ssd_chunk_bhcp(x, dt, dacum, B, C, *, interpret):
         out_specs=[tile(1, 1, l, p), tile(1, 1, n, p)],
         out_shape=[jax.ShapeDtypeStruct((bc, h, l, p), x.dtype),
                    jax.ShapeDtypeStruct((bc, h, n, p), jnp.float32)],
+        name="ssd_chunk",
         interpret=interpret,
     )(x, dt[..., None], dacum[..., None], dacum[:, :, None, :], B, C)

@@ -284,19 +284,22 @@ def apply_attention(params, x, cfg, *, positions=None, causal=True,
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
         qg = _group(q, kv)
-        if cfg.attn_impl == "pallas" and not cross:
-            # Pallas flash kernel (TPU target; interpret mode on CPU) —
-            # keeps the score tiles in VMEM (EXPERIMENTS.md §Perf A2)
-            from repro.kernels import ops as kops
-            o = kops.flash_attention(q, k, v, causal=causal, window=window)
-            o = _group(o, kv)
-        elif cfg.attn_impl == "blocked" and not cross:
-            o = blocked_attention(qg, k, v, causal=causal, window=window,
-                                  block_q=cfg.attn_block_q,
-                                  block_kv=cfg.attn_block_kv)
-        else:
-            o = naive_attention(qg, k, v, causal=causal and not cross,
-                                window=window)
+        with jax.named_scope("attend"):
+            if cfg.attn_impl == "pallas" and not cross:
+                # Pallas flash kernel (TPU target; interpret mode on
+                # CPU) — keeps the score tiles in VMEM (EXPERIMENTS.md
+                # §Perf A2)
+                from repro.kernels import ops as kops
+                o = kops.flash_attention(q, k, v, causal=causal,
+                                         window=window)
+                o = _group(o, kv)
+            elif cfg.attn_impl == "blocked" and not cross:
+                o = blocked_attention(qg, k, v, causal=causal, window=window,
+                                      block_q=cfg.attn_block_q,
+                                      block_kv=cfg.attn_block_kv)
+            else:
+                o = naive_attention(qg, k, v, causal=causal and not cross,
+                                    window=window)
         y = o.reshape(b, x.shape[1], h * cfg.head_dim)
         y = jnp.einsum("bsk,kd->bsd", y, params["wo"].astype(dt))
         new_cache = None
@@ -337,17 +340,18 @@ def apply_attention(params, x, cfg, *, positions=None, causal=True,
                          jnp.minimum(pos, sview - 1), sview)
         kc = kc.at[rows, wpos].set(k[:, 0].astype(kc.dtype))
         vc = vc.at[rows, wpos].set(v[:, 0].astype(vc.dtype))
-        if cfg.attn_impl == "pallas":
-            # view-resident decode attend: the kernel indexes the
-            # contiguous view directly inside the fori_loop (per-row
-            # positions via scalar prefetch) — no jnp gather/softmax
-            # materialization per iteration
-            from repro.kernels import ops as kops
-            o = kops.decode_view_attend(q[:, 0], kc, vc, pos,
-                                        window=window)[:, None]
-        else:
-            o = paged_decode_attention(_group(q, kv), kc, vc, positions,
-                                       window=window)
+        with jax.named_scope("attend"):
+            if cfg.attn_impl == "pallas":
+                # view-resident decode attend: the kernel indexes the
+                # contiguous view directly inside the fori_loop (per-row
+                # positions via scalar prefetch) — no jnp gather/softmax
+                # materialization per iteration
+                from repro.kernels import ops as kops
+                o = kops.decode_view_attend(q[:, 0], kc, vc, pos,
+                                            window=window)[:, None]
+            else:
+                o = paged_decode_attention(_group(q, kv), kc, vc, positions,
+                                           window=window)
         y = o.reshape(b, 1, h * cfg.head_dim)
         y = jnp.einsum("bsk,kd->bsd", y, params["wo"].astype(dt))
         return y, {"kview": kc, "vview": vc}
@@ -372,17 +376,18 @@ def apply_attention(params, x, cfg, *, positions=None, causal=True,
         blk, slot = paged_write_indices(positions, bt, bs_blk, valid_len)
         kpool = kpool.at[blk, slot].set(k.astype(kpool.dtype))
         vpool = vpool.at[blk, slot].set(v.astype(vpool.dtype))
-        if cfg.attn_impl == "pallas":
-            from repro.kernels import ops as kops
-            o = kops.flash_decode_paged(q, kpool, vpool, bt, pos,
-                                        window=window)
-            o = o.reshape(b, c, kv, h // kv, cfg.head_dim)
-        else:
-            nb_seq = bt.shape[1]
-            kc = kpool[bt].reshape(b, nb_seq * bs_blk, kv, cfg.head_dim)
-            vc = vpool[bt].reshape(b, nb_seq * bs_blk, kv, cfg.head_dim)
-            o = paged_decode_attention(_group(q, kv), kc, vc, positions,
-                                       window=window)
+        with jax.named_scope("attend"):
+            if cfg.attn_impl == "pallas":
+                from repro.kernels import ops as kops
+                o = kops.flash_decode_paged(q, kpool, vpool, bt, pos,
+                                            window=window)
+                o = o.reshape(b, c, kv, h // kv, cfg.head_dim)
+            else:
+                nb_seq = bt.shape[1]
+                kc = kpool[bt].reshape(b, nb_seq * bs_blk, kv, cfg.head_dim)
+                vc = vpool[bt].reshape(b, nb_seq * bs_blk, kv, cfg.head_dim)
+                o = paged_decode_attention(_group(q, kv), kc, vc, positions,
+                                           window=window)
         y = o.reshape(b, c, h * cfg.head_dim)
         y = jnp.einsum("bsk,kd->bsd", y, params["wo"].astype(dt))
         return y, {"k": kpool, "v": vpool, "block_tables": bt}
@@ -395,8 +400,8 @@ def apply_attention(params, x, cfg, *, positions=None, causal=True,
         if "bq" in params:
             q = q + params["bq"].astype(dt)
         q = q.reshape(b, 1, h, cfg.head_dim)
-        qg = _group(q, kv)
-        o = naive_attention(qg, kc, vc, causal=False)
+        with jax.named_scope("attend"):
+            o = naive_attention(_group(q, kv), kc, vc, causal=False)
         y = o.reshape(b, 1, h * cfg.head_dim)
         y = jnp.einsum("bsk,kd->bsd", y, params["wo"].astype(dt))
         return y, cache
@@ -408,8 +413,8 @@ def apply_attention(params, x, cfg, *, positions=None, causal=True,
     slot = pos % sc
     kc = kc.at[:, slot].set(k[:, 0].astype(kc.dtype))
     vc = vc.at[:, slot].set(v[:, 0].astype(vc.dtype))
-    qg = _group(q, kv)
-    o = decode_attention(qg, kc, vc, pos, window=window)
+    with jax.named_scope("attend"):
+        o = decode_attention(_group(q, kv), kc, vc, pos, window=window)
     y = o.reshape(b, 1, h * cfg.head_dim)
     y = jnp.einsum("bsk,kd->bsd", y, params["wo"].astype(dt))
     return y, {"k": kc, "v": vc}

@@ -70,6 +70,7 @@ def init_mlp(key, cfg, d_model: Optional[int] = None, d_ff: Optional[int] = None
             "w_down": dense_init(ks[1], (f, d), pd)}
 
 
+@jax.named_scope("mlp")
 def apply_mlp(params, x, cfg):
     if "w_gate" in params:
         g = jnp.einsum("...d,df->...f", x, params["w_gate"].astype(x.dtype))

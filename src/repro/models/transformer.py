@@ -235,6 +235,7 @@ def embed_tokens(params, tokens, cfg):
     return jnp.take(emb, tokens, axis=0).astype(cfg.cdtype)
 
 
+@jax.named_scope("lm_head_loss")
 def chunked_lm_ce(params, h, labels, cfg, *, mask_from: int = 0):
     """Cross-entropy over sequence chunks: the (B, C, V) logits chunk is
     the only vocab-sized activation alive (vs (B, S, V) in one shot).
@@ -415,6 +416,7 @@ def paged_step_logits(params, cache, tokens, pos, cfg):
     return logits, new_cache
 
 
+@jax.named_scope("paged_step")
 def paged_step(params, cache, slot_buf, tokens, block_tables, meta, cfg,
                *, temperature: float = 0.0, top_k: int = 0, seed: int = 0):
     """Fused continuous-batching step over a paged cache: mixed
@@ -485,6 +487,7 @@ def paged_step(params, cache, slot_buf, tokens, block_tables, meta, cfg,
     return toks, slot_buf, _canonical_block_tables(new_cache)
 
 
+@jax.named_scope("sample")
 def _sample_rows(logits, rids, positions, *, temperature, top_k, seed,
                  impl="jnp"):
     """Sample one token per row on device.  The sampled token's key is a
@@ -513,6 +516,7 @@ def _paged_block_size(cache):
     return 0
 
 
+@jax.named_scope("gather_view")
 def _gather_view(pool, bt):
     """(L, nb, bs, ...) pool + (B, NB) tables -> (B, NB*bs + 1, ...)
     per-row contiguous views with one trailing trash slot (index S) for
@@ -525,6 +529,7 @@ def _gather_view(pool, bt):
     return jnp.concatenate([v, pad], axis=2)
 
 
+@jax.named_scope("scatter_view")
 def _scatter_view(pool, bt, view):
     """Write the (trash-slot-stripped) views back through the tables.
     Real blocks belong to exactly one row, so the only duplicate scatter
@@ -616,6 +621,7 @@ def _scatter_loop_views(cache, views, block_tables, state_slot, cfg=None):
     return out
 
 
+@jax.named_scope("paged_decode_loop")
 def paged_decode_loop(params, cache, slot_buf, block_tables, meta, cfg,
                       *, num_steps: int, temperature: float = 0.0,
                       top_k: int = 0, seed: int = 0):

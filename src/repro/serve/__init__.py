@@ -16,8 +16,8 @@ model interface (Model.init_paged_cache / Model.paged_step).
                        only admission/results/metrics
   telemetry            metrics registry (counters/gauges/histograms with
                        labels), per-request lifecycle tracing (TTFT /
-                       TPOT / e2e histograms), Chrome-trace span
-                       timelines, JSONL snapshot export
+                       TPOT / e2e histograms), host spans in the JAX
+                       profiler's trace, JSONL snapshot export
   faults               replica lifecycle states, health/retry policy,
                        deterministic chaos-injection plans (the
                        dispatcher's fault-tolerance knobs)

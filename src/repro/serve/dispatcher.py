@@ -97,10 +97,11 @@ class ServeCluster:
     metric handles keep engines apart in the registry, the request
     trace book sees the whole lifecycle (dispatcher stamps
     submit/route/retry, the owning engine stamps
-    admit/first_token/terminal), and the span tracer gets one
-    ``replica{i}/host`` + ``replica{i}/device`` track pair per worker
-    plus a ``dispatcher`` track.  Pass ``trace=True`` (or a pre-built
-    ``telemetry=``) to turn span tracing on; metrics are always on.
+    admit/first_token/terminal), and the span tracer writes each
+    worker's ``repro:engine.*`` spans and the ``repro:dispatcher.route``
+    spans into the profiler's trace, on the thread that ran them.  Pass
+    ``trace=True`` (or a pre-built ``telemetry=``) to turn spans on;
+    metrics are always on.
 
     Fault-tolerance knobs: ``health`` (heartbeat deadlines), ``retry``
     (backoff + poison threshold), ``faults`` (a deterministic chaos
@@ -360,56 +361,52 @@ class ServeCluster:
         replica can ever admit it (all DEAD/DRAINING).  Returns the
         replica_id it landed on."""
         weight = int(req.prompt.size) + req.max_new_tokens
-        t_sub = time.perf_counter()
-        self.telemetry.requests.stamp(req.rid, "submit", t=t_sub)
-        req.start_clock()       # arm deadline budgets at the front door
-        deadline = None if timeout is None else time.monotonic() + timeout
-        with self._cv:
-            replica = self.router.route(req.rid, tokens=weight)
-            while replica is None:
-                if self._errors:
-                    raise self._errors[0]
-                if not self._any_admittable():
-                    raise NoLiveReplicas(
-                        f"request {req.rid}: every replica is DEAD or "
-                        "DRAINING")
-                if self.shed_overload:
-                    self._shed.inc()
-                    raise Overloaded(
-                        f"request {req.rid}: every live replica past "
-                        f"capacity_tokens={self.router.capacity_tokens}")
-                wait = (None if deadline is None
-                        else deadline - time.monotonic())
-                if wait is not None and wait <= 0:
-                    raise TimeoutError(
-                        f"request {req.rid}: every replica saturated for "
-                        f"{timeout}s (capacity_tokens="
-                        f"{self.router.capacity_tokens})")
-                self._cv.wait(wait)
+        with self.telemetry.tracer.span("dispatcher", "route", rid=req.rid,
+                                        weight=weight):
+            self.telemetry.requests.stamp(req.rid, "submit")
+            req.start_clock()       # arm deadline budgets at the front door
+            deadline = None if timeout is None else time.monotonic() + timeout
+            with self._cv:
                 replica = self.router.route(req.rid, tokens=weight)
-            # queue-submit INSIDE the lock: route+enqueue are atomic
-            # against a concurrent queue reclaim (replica death), so a
-            # routed request is always either in a queue the failover
-            # path drains or in _picked under a snapshot
-            try:
-                self._queues[replica.replica_id].submit(req)
-            except BaseException:
-                # admission refused (queue closed mid-submit): the
-                # routed weight must not leak
-                self.router.release(req.rid)
-                self._cv.notify_all()
-                raise
-            self._snapshots[req.rid] = _Snapshot(
-                prompt=req.prompt.copy(),
-                max_new_tokens=req.max_new_tokens, eos_id=req.eos_id,
-                arrival_time=req.arrival_time, deadline_at=req.deadline_at,
-                queue_deadline_at=req.queue_deadline_at)
-        t_routed = time.perf_counter()
-        self.telemetry.requests.stamp(req.rid, "route", t=t_routed)
-        self.telemetry.tracer.span(
-            "dispatcher", f"route:{req.rid}", t_sub, t_routed,
-            args={"rid": req.rid, "replica": replica.replica_id,
-                  "weight": weight})
+                while replica is None:
+                    if self._errors:
+                        raise self._errors[0]
+                    if not self._any_admittable():
+                        raise NoLiveReplicas(
+                            f"request {req.rid}: every replica is DEAD or "
+                            "DRAINING")
+                    if self.shed_overload:
+                        self._shed.inc()
+                        raise Overloaded(
+                            f"request {req.rid}: every live replica past "
+                            f"capacity_tokens={self.router.capacity_tokens}")
+                    wait = (None if deadline is None
+                            else deadline - time.monotonic())
+                    if wait is not None and wait <= 0:
+                        raise TimeoutError(
+                            f"request {req.rid}: every replica saturated for "
+                            f"{timeout}s (capacity_tokens="
+                            f"{self.router.capacity_tokens})")
+                    self._cv.wait(wait)
+                    replica = self.router.route(req.rid, tokens=weight)
+                # queue-submit INSIDE the lock: route+enqueue are atomic
+                # against a concurrent queue reclaim (replica death), so a
+                # routed request is always either in a queue the failover
+                # path drains or in _picked under a snapshot
+                try:
+                    self._queues[replica.replica_id].submit(req)
+                except BaseException:
+                    # admission refused (queue closed mid-submit): the
+                    # routed weight must not leak
+                    self.router.release(req.rid)
+                    self._cv.notify_all()
+                    raise
+                self._snapshots[req.rid] = _Snapshot(
+                    prompt=req.prompt.copy(),
+                    max_new_tokens=req.max_new_tokens, eos_id=req.eos_id,
+                    arrival_time=req.arrival_time, deadline_at=req.deadline_at,
+                    queue_deadline_at=req.queue_deadline_at)
+        self.telemetry.requests.stamp(req.rid, "route")
         return replica.replica_id
 
     def cancel(self, rid: int) -> bool:
@@ -873,11 +870,6 @@ class ServeCluster:
                         "pending": len(self._pending_failover)}
         return {"aggregate": {"counters": counters, "latency": latency},
                 "per_replica": per, "health": health, "failover": failover}
-
-    def write_trace(self, path: str) -> None:
-        """Export the span timeline as Chrome ``trace_event`` JSON
-        (open in Perfetto / chrome://tracing)."""
-        self.telemetry.write_trace(path)
 
     def write_metrics(self, path: str) -> None:
         """Write the full registry snapshot plus the structured

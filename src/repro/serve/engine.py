@@ -261,8 +261,7 @@ class _Inflight:
     counts: Optional[jax.Array] = None    # (rows,) int32, loop only
     eos_hit: Optional[jax.Array] = None   # (rows,) bool, loop only
     planned: Optional[Dict[int, int]] = None   # row -> granted steps
-    t_disp: float = 0.0                   # tracer only: dispatch-return time
-    label: str = ""                       # tracer only: device-span name
+    step: int = 0                         # the engine step that dispatched it
 
 
 # analysis: single-writer — an Engine is thread-confined by contract:
@@ -320,12 +319,6 @@ class Engine:
         self.replica_id = replica_id
         self._m = _EngineMetrics(self.telemetry.registry,
                                  replica=replica_id, arch=model.cfg.name)
-        self._host_track = f"replica{replica_id}/host"
-        self._dev_track = f"replica{replica_id}/device"
-        # device spans serialize on one track: the device executes
-        # dispatches in order, so span k+1 starts no earlier than span
-        # k's end even when the host dispatched it mid-flight
-        self._dev_tail = 0.0
         self.devices = tuple(devices) if devices else None
         self.device = self.devices[0] if self.devices else None
         self.tp_degree = len(self.devices) if self.devices else 1
@@ -785,25 +778,13 @@ class Engine:
         slot / spare token slot or in blocks that are rewritten before
         any live query attends them, so nothing live is perturbed."""
         rec = self._pending.popleft()
-        tr = self.telemetry.tracer
-        ts0 = time.perf_counter() if tr.enabled else 0.0
-        toks = np.asarray(rec.toks)            # sync point
+        with self.telemetry.tracer.span("engine", "fetch", step=rec.step):
+            toks = np.asarray(rec.toks)        # sync point
+            if rec.counts is not None:         # N-step decode-loop record
+                counts = np.asarray(rec.counts)
+                eos_hit = np.asarray(rec.eos_hit)
         self._m.host_syncs.inc()
-        if tr.enabled:
-            ts1 = time.perf_counter()
-            tr.span(self._host_track, "fetch", ts0, ts1)
-            if rec.label:
-                # the host-observed envelope of this dispatch's device
-                # execution: from dispatch return (or the previous
-                # dispatch's completion — the device runs them in
-                # order) to the fetch landing
-                d0 = max(rec.t_disp, self._dev_tail)
-                d1 = max(ts1, d0)
-                tr.span(self._dev_track, rec.label, d0, d1)
-                self._dev_tail = d1
-        if rec.counts is not None:             # N-step decode-loop record
-            counts = np.asarray(rec.counts)
-            eos_hit = np.asarray(rec.eos_hit)
+        if rec.counts is not None:
             for row, seq, _ in rec.emits:
                 if seq not in self._live or seq.desync:
                     continue                   # evicted by an earlier fetch
@@ -884,13 +865,13 @@ class Engine:
 
     def _step_fused(self, now: float, finished: List[RequestResult]) -> None:
         cfg = self.cfg
-        tr = self.telemetry.tracer
-        t_plan0 = time.perf_counter() if tr.enabled else 0.0
+        span = self.telemetry.tracer.span
         if self._desynced:
             # a device-truncated sequence has mis-positioned dispatches
             # in flight; resolve (flush + recompute) before planning
             self._flush(finished)
-        plan = self.scheduler.schedule(len(self._live), self.kv)
+        with span("engine", "schedule"):
+            plan = self.scheduler.schedule(len(self._live), self.kv)
         active = [s for s in self._live
                   if s.prefill_done and not s.done][:cfg.max_batch]
         if cfg.steps_per_dispatch > 1 and active and not plan:
@@ -898,29 +879,30 @@ class Engine:
             # device.  Prefill/mixed steps stay single-step calls —
             # admission and preemption only happen at these dispatch
             # boundaries, every N tokens.
-            self._dispatch_decode_loop(active, now, finished,
-                                       t_plan0=t_plan0)
+            self._dispatch_decode_loop(active, now, finished)
             return
         # grow each decoding sequence's table to cover the token being
         # written; preempt LIFO victims if the pool is out of blocks
-        for seq in active:
-            if seq not in self._live:
-                # a preemption on an earlier row's behalf evicted this
-                # one — growing its table now would hand the just-freed
-                # blocks straight back to the dead rid
-                continue
-            while not self.kv.ensure_capacity(seq.req.rid, seq.next_pos + 1,
-                                              query_start=seq.next_pos):
-                if self._pending:
-                    # finished-but-unfetched sequences may be holding
-                    # blocks; materialize them before sacrificing a
-                    # victim (preemption also needs token values on host)
-                    self._flush(finished)
+        with span("engine", "reserve"):
+            for seq in active:
+                if seq not in self._live:
+                    # a preemption on an earlier row's behalf evicted this
+                    # one — growing its table now would hand the just-freed
+                    # blocks straight back to the dead rid
                     continue
-                if not self._preempt_one(exclude_rid=seq.req.rid):
-                    raise RuntimeError(
-                        "KV pool too small for a single sequence; raise "
-                        "num_blocks or lower max_seq_len")
+                while not self.kv.ensure_capacity(
+                        seq.req.rid, seq.next_pos + 1,
+                        query_start=seq.next_pos):
+                    if self._pending:
+                        # finished-but-unfetched sequences may be holding
+                        # blocks; materialize them before sacrificing a
+                        # victim (preemption also needs token values on host)
+                        self._flush(finished)
+                        continue
+                    if not self._preempt_one(exclude_rid=seq.req.rid):
+                        raise RuntimeError(
+                            "KV pool too small for a single sequence; raise "
+                            "num_blocks or lower max_seq_len")
         # preemption (or an eos eviction inside the flush) may have
         # removed members of `active` or owners of planned chunks
         active = [s for s in active if s in self._live]
@@ -957,105 +939,101 @@ class Engine:
         #                   the call* — so their mixed layout keeps
         #                   chunk-wide prefill rows and pads decode rows
         #                   to the chunk width (valid_len=1).
-        n_dec = len(active)
-        n_pre = sum(ch.length for ch in plan)
-        if n_pre == 0:
-            rows, width = min(k for k in cfg.decode_buckets
-                              if k >= n_dec), 1
-        elif n_dec == 0:
-            rows, width = cfg.prefill_rows, cfg.prefill_chunk
-        elif self.spec.width1_mixed:
-            rows, width = min(k for k in cfg.mixed_buckets
-                              if k >= n_dec + n_pre), 1
-        else:
-            rows, width = cfg.mixed_chunk_rows, cfg.prefill_chunk
-        tokens = np.zeros((rows, width), np.int32)
-        meta = np.zeros((6, rows), np.int32)
-        meta[2:4] = -1
-        pos, valid, src, dst, state, rid_row = meta
-        rids: List[Optional[int]] = [None] * rows
-        emits: List[Tuple[int, _Seq, bool]] = []
-        slot_of = (self.state_slots.slot_of if self.state_slots is not None
-                   else lambda rid: 0)
+        with span("engine", "build"):
+            n_dec = len(active)
+            n_pre = sum(ch.length for ch in plan)
+            if n_pre == 0:
+                rows, width = min(k for k in cfg.decode_buckets
+                                  if k >= n_dec), 1
+            elif n_dec == 0:
+                rows, width = cfg.prefill_rows, cfg.prefill_chunk
+            elif self.spec.width1_mixed:
+                rows, width = min(k for k in cfg.mixed_buckets
+                                  if k >= n_dec + n_pre), 1
+            else:
+                rows, width = cfg.mixed_chunk_rows, cfg.prefill_chunk
+            tokens = np.zeros((rows, width), np.int32)
+            meta = np.zeros((6, rows), np.int32)
+            meta[2:4] = -1
+            pos, valid, src, dst, state, rid_row = meta
+            rids: List[Optional[int]] = [None] * rows
+            emits: List[Tuple[int, _Seq, bool]] = []
+            slot_of = (self.state_slots.slot_of if self.state_slots is not None
+                       else lambda rid: 0)
 
-        for row, seq in enumerate(active):
-            pos[row] = seq.next_pos
-            valid[row] = 1
-            rids[row] = seq.req.rid
-            rid_row[row] = seq.req.rid
-            state[row] = slot_of(seq.req.rid)
-            dst[row] = seq.slot
-            # the slot buffer always holds this sequence's latest
-            # sampled token (greedy AND stochastic — sampling is on
-            # device) — no host round-trip
-            src[row] = seq.slot
-            emits.append((row, seq, False))
-            self.telemetry.requests.note_dispatch(seq.req.rid)
-            seq.gen_count += 1
-            if seq.gen_count >= seq.req.max_new_tokens:
-                seq.done = True
-        row = n_dec
-        for ch in plan:
-            seq = self._seq_of(ch.req.rid)
-            if seq is None:                    # fresh admission
-                seq = self._admit(ch.req)
-            self._m.prefill_tokens.inc(ch.length)
-            self.telemetry.requests.stamp(ch.req.rid, "prefill_start")
-            completes = ch.start + ch.length >= len(ch.req.prompt)
-            chunk_tok = ch.req.prompt[ch.start:ch.start + ch.length]
-            if width > 1:                      # chunk-wide: one row/chunk
-                tokens[row, :ch.length] = chunk_tok
-                pos[row] = ch.start
-                valid[row] = ch.length
-                rids[row] = ch.req.rid
-                rid_row[row] = ch.req.rid
-                state[row] = slot_of(ch.req.rid)
-                if completes:
-                    # prompt complete: the frontier logit is the first
-                    # generated token
-                    dst[row] = seq.slot
-                    seq.prefill_done = True
-                    emits.append((row, seq, True))
-                    seq.gen_count += 1
-                    if seq.gen_count >= seq.req.max_new_tokens:
-                        seq.done = True
-                row += 1
-                continue
-            for i in range(ch.length):         # mixed: one row/token
-                tokens[row, 0] = chunk_tok[i]
-                pos[row] = ch.start + i
+            for row, seq in enumerate(active):
+                pos[row] = seq.next_pos
                 valid[row] = 1
-                rids[row] = ch.req.rid
-                rid_row[row] = ch.req.rid
-                if completes and i == ch.length - 1:
-                    dst[row] = seq.slot
-                    seq.prefill_done = True
-                    emits.append((row, seq, True))
-                    seq.gen_count += 1
-                    if seq.gen_count >= seq.req.max_new_tokens:
-                        seq.done = True
-                row += 1
+                rids[row] = seq.req.rid
+                rid_row[row] = seq.req.rid
+                state[row] = slot_of(seq.req.rid)
+                dst[row] = seq.slot
+                # the slot buffer always holds this sequence's latest
+                # sampled token (greedy AND stochastic — sampling is on
+                # device) — no host round-trip
+                src[row] = seq.slot
+                emits.append((row, seq, False))
+                self.telemetry.requests.note_dispatch(seq.req.rid)
+                seq.gen_count += 1
+                if seq.gen_count >= seq.req.max_new_tokens:
+                    seq.done = True
+            row = n_dec
+            for ch in plan:
+                seq = self._seq_of(ch.req.rid)
+                if seq is None:                    # fresh admission
+                    seq = self._admit(ch.req)
+                self._m.prefill_tokens.inc(ch.length)
+                self.telemetry.requests.stamp(ch.req.rid, "prefill_start")
+                completes = ch.start + ch.length >= len(ch.req.prompt)
+                chunk_tok = ch.req.prompt[ch.start:ch.start + ch.length]
+                if width > 1:                      # chunk-wide: one row/chunk
+                    tokens[row, :ch.length] = chunk_tok
+                    pos[row] = ch.start
+                    valid[row] = ch.length
+                    rids[row] = ch.req.rid
+                    rid_row[row] = ch.req.rid
+                    state[row] = slot_of(ch.req.rid)
+                    if completes:
+                        # prompt complete: the frontier logit is the first
+                        # generated token
+                        dst[row] = seq.slot
+                        seq.prefill_done = True
+                        emits.append((row, seq, True))
+                        seq.gen_count += 1
+                        if seq.gen_count >= seq.req.max_new_tokens:
+                            seq.done = True
+                    row += 1
+                    continue
+                for i in range(ch.length):         # mixed: one row/token
+                    tokens[row, 0] = chunk_tok[i]
+                    pos[row] = ch.start + i
+                    valid[row] = 1
+                    rids[row] = ch.req.rid
+                    rid_row[row] = ch.req.rid
+                    if completes and i == ch.length - 1:
+                        dst[row] = seq.slot
+                        seq.prefill_done = True
+                        emits.append((row, seq, True))
+                        seq.gen_count += 1
+                        if seq.gen_count >= seq.req.max_new_tokens:
+                            seq.done = True
+                    row += 1
 
-        phase = ("decode" if n_pre == 0
-                 else "prefill" if n_dec == 0 else "mixed")
-        t0 = time.perf_counter()
-        toks = self._dispatch(tokens, meta, self.kv.table_array(rids))
-        t1 = time.perf_counter()
+            phase = ("decode" if n_pre == 0
+                     else "prefill" if n_dec == 0 else "mixed")
+            tables = self.kv.table_array(rids)
+        with span("engine", "dispatch", phase=phase, rows=rows, width=width):
+            t0 = time.perf_counter()
+            toks = self._dispatch(tokens, meta, tables)
+            t1 = time.perf_counter()
         self._m.dispatch_s[phase].observe(t1 - t0)
         if n_dec:
             self._m.decode_steps.inc()
             self._m.decode_slot_steps.inc(rows if n_pre == 0
                                           else cfg.max_batch)
             self._m.decode_active_slot_steps.inc(n_dec)
-        rec = _Inflight(toks, emits, now)
-        if tr.enabled:
-            tr.span(self._host_track, "plan", t_plan0, t0,
-                    args={"decode_rows": n_dec, "prefill_tokens": n_pre})
-            tr.span(self._host_track, f"dispatch:{phase}", t0, t1,
-                    args={"rows": rows, "width": width})
-            rec.t_disp = t1
-            rec.label = f"{phase}[{rows}x{width}]"
-        self._pending.append(rec)
+        self._pending.append(_Inflight(toks, emits, now,
+                                       step=self._m.steps.value))
         if not cfg.pipeline:
             self._flush(finished)
         else:
@@ -1065,8 +1043,7 @@ class Engine:
                 self._fetch_one(finished)
 
     def _dispatch_decode_loop(self, active: List[_Seq], now: float,
-                              finished: List[RequestResult],
-                              t_plan0: float = 0.0) -> None:
+                              finished: List[RequestResult]) -> None:
         """One N-step on-device decode dispatch (N =
         ``steps_per_dispatch``): reserve per-row headroom for up to N
         tokens (blocks for block-pool families, metered tokens for
@@ -1083,88 +1060,84 @@ class Engine:
         boundary, so a partially-granted row exits its loop early
         instead of writing through the trash block."""
         cfg = self.cfg
+        span = self.telemetry.tracer.span
         n_steps = cfg.steps_per_dispatch
         grants: Dict[int, Tuple[int, int]] = {}    # rid -> (want, granted)
-        for seq in active:
-            if seq not in self._live:
-                continue     # evicted/preempted on an earlier row's behalf
-            want = min(n_steps, seq.req.max_new_tokens - seq.gen_count)
-            while True:
-                covered = self.kv.reserve(seq.req.rid,
-                                          seq.next_pos + want,
-                                          query_start=seq.next_pos)
-                granted = min(want, covered - seq.next_pos)
-                if granted >= 1:
-                    break
-                if self._pending:
-                    # finished-but-unfetched sequences may be holding
-                    # blocks; materialize them before sacrificing a
-                    # victim
-                    self._flush(finished)
-                    if seq not in self._live:
+        with span("engine", "reserve"):
+            for seq in active:
+                if seq not in self._live:
+                    continue     # evicted/preempted on an earlier row's behalf
+                want = min(n_steps, seq.req.max_new_tokens - seq.gen_count)
+                while True:
+                    covered = self.kv.reserve(seq.req.rid,
+                                              seq.next_pos + want,
+                                              query_start=seq.next_pos)
+                    granted = min(want, covered - seq.next_pos)
+                    if granted >= 1:
                         break
-                    continue
-                if not self._preempt_one(exclude_rid=seq.req.rid):
-                    raise RuntimeError(
-                        "KV pool too small for a single sequence; raise "
-                        "num_blocks or lower max_seq_len")
-            if seq in self._live:
-                grants[seq.req.rid] = (want, granted)
+                    if self._pending:
+                        # finished-but-unfetched sequences may be holding
+                        # blocks; materialize them before sacrificing a
+                        # victim
+                        self._flush(finished)
+                        if seq not in self._live:
+                            break
+                        continue
+                    if not self._preempt_one(exclude_rid=seq.req.rid):
+                        raise RuntimeError(
+                            "KV pool too small for a single sequence; raise "
+                            "num_blocks or lower max_seq_len")
+                if seq in self._live:
+                    grants[seq.req.rid] = (want, granted)
         rows_seqs = [s for s in active
                      if s in self._live and s.req.rid in grants]
         if not rows_seqs:
             self._flush(finished)
             return
-        rows = min(k for k in cfg.decode_buckets if k >= len(rows_seqs))
-        meta = np.zeros((6, rows), np.int32)
-        pos0, steps, slot, state, rid_row, eos = meta
-        eos[:] = -1
-        slot_of = (self.state_slots.slot_of if self.state_slots is not None
-                   else lambda rid: 0)
-        emits: List[Tuple[int, _Seq, bool]] = []
-        planned: Dict[int, int] = {}
-        rids: List[Optional[int]] = [None] * rows
-        for row, seq in enumerate(rows_seqs):
-            want, granted = grants[seq.req.rid]
-            pos0[row] = seq.next_pos
-            steps[row] = granted
-            slot[row] = seq.slot
-            state[row] = slot_of(seq.req.rid)
-            rid_row[row] = seq.req.rid
-            eos[row] = (-1 if seq.req.eos_id is None else seq.req.eos_id)
-            rids[row] = seq.req.rid
-            if granted < want:
-                self._m.loop_truncations.inc()
-            planned[row] = granted
-            emits.append((row, seq, False))
-            self.telemetry.requests.note_dispatch(seq.req.rid)
-            seq.gen_count += granted
-            if seq.gen_count >= seq.req.max_new_tokens:
-                seq.done = True
+        with span("engine", "build"):
+            rows = min(k for k in cfg.decode_buckets if k >= len(rows_seqs))
+            meta = np.zeros((6, rows), np.int32)
+            pos0, steps, slot, state, rid_row, eos = meta
+            eos[:] = -1
+            slot_of = (self.state_slots.slot_of if self.state_slots is not None
+                       else lambda rid: 0)
+            emits: List[Tuple[int, _Seq, bool]] = []
+            planned: Dict[int, int] = {}
+            rids: List[Optional[int]] = [None] * rows
+            for row, seq in enumerate(rows_seqs):
+                want, granted = grants[seq.req.rid]
+                pos0[row] = seq.next_pos
+                steps[row] = granted
+                slot[row] = seq.slot
+                state[row] = slot_of(seq.req.rid)
+                rid_row[row] = seq.req.rid
+                eos[row] = (-1 if seq.req.eos_id is None else seq.req.eos_id)
+                rids[row] = seq.req.rid
+                if granted < want:
+                    self._m.loop_truncations.inc()
+                planned[row] = granted
+                emits.append((row, seq, False))
+                self.telemetry.requests.note_dispatch(seq.req.rid)
+                seq.gen_count += granted
+                if seq.gen_count >= seq.req.max_new_tokens:
+                    seq.done = True
+            tables = self.kv.table_array(rids)
         self._m.model_calls.inc()
         self._m.loop_dispatches.inc()
         max_granted = max(planned.values())
         self._m.decode_steps.inc(max_granted)
         self._m.decode_slot_steps.inc(rows * max_granted)
         self._m.decode_active_slot_steps.inc(sum(planned.values()))
-        tr = self.telemetry.tracer
-        t0 = time.perf_counter()
-        out, counts, eos_hit, self._slot_buf, self.cache = self._loop_fn(
-            self.params, self.cache, self._slot_buf,
-            self.kv.table_array(rids), meta)
-        t1 = time.perf_counter()
+        with span("engine", "dispatch", phase="loop", rows=rows, width=1,
+                  steps=n_steps):
+            t0 = time.perf_counter()
+            out, counts, eos_hit, self._slot_buf, self.cache = self._loop_fn(
+                self.params, self.cache, self._slot_buf, tables, meta)
+            t1 = time.perf_counter()
         self._m.dispatch_s["loop"].observe(t1 - t0)
-        rec = _Inflight(out, emits, now, counts=counts,
-                        eos_hit=eos_hit, planned=planned)
-        if tr.enabled:
-            tr.span(self._host_track, "plan", t_plan0, t0,
-                    args={"decode_rows": len(rows_seqs),
-                          "steps": n_steps})
-            tr.span(self._host_track, "dispatch:loop", t0, t1,
-                    args={"rows": rows, "steps": n_steps})
-            rec.t_disp = t1
-            rec.label = f"loop[{rows}x{n_steps}]"
-        self._pending.append(rec)
+        self._pending.append(_Inflight(out, emits, now, counts=counts,
+                                       eos_hit=eos_hit, planned=planned,
+                                       step=self._m.steps.value))
         if not cfg.pipeline:
             self._flush(finished)
         else:
@@ -1331,19 +1304,25 @@ class Engine:
             jax.block_until_ready(self._pending[-1].toks)
 
     def step(self, now: Optional[float] = None) -> List[RequestResult]:
-        """One engine iteration; returns requests finished this step."""
+        """One engine iteration; returns requests finished this step.
+        With tracing on it is the ``repro:engine.step`` span, numbered
+        as the ``steps`` counter counts; the dispatch it makes is
+        fetched under an ``engine.fetch`` span carrying that number."""
         now = time.perf_counter() if now is None else now
         finished: List[RequestResult] = []
-        if self._has_deadlines:
-            self._expire_deadlines(finished)
-        if self.cfg.fused:
-            self._step_fused(now, finished)
-        else:
-            plan = self.scheduler.schedule(len(self._live), self.kv)
-            self._prefill_legacy(plan, now, finished)
-            self._decode_legacy(now, finished)
-        self._m.steps.inc()
-        self._note_compiles()
+        span = self.telemetry.tracer.span
+        with span("engine", "step", step=self._m.steps.value):
+            if self._has_deadlines:
+                self._expire_deadlines(finished)
+            if self.cfg.fused:
+                self._step_fused(now, finished)
+            else:
+                with span("engine", "schedule"):
+                    plan = self.scheduler.schedule(len(self._live), self.kv)
+                self._prefill_legacy(plan, now, finished)
+                self._decode_legacy(now, finished)
+            self._m.steps.inc()
+            self._note_compiles()
         return finished
 
     def run(self, requests: Sequence[Request] = (),

@@ -1,5 +1,5 @@
 """Serving telemetry: metrics registry, request lifecycle tracing, and
-Chrome-trace dispatch timelines.
+host spans on the device trace's clock.
 
 The LSGD paper's central claim is a *timing* claim — slow communication
 hidden under other work — and the serving stack makes the same claim
@@ -20,30 +20,32 @@ that claim stops being an argument and becomes a measurement:
     never silently merged — the invariant tests assert the counter is
     zero); ``finish()`` derives queue-wait, TTFT, per-output-token
     latency (TPOT), and end-to-end into registry histograms.
-  * ``SpanTracer`` — span timelines exported as Chrome ``trace_event``
-    JSON (``{"traceEvents": [...]}``), one track per replica worker
-    thread plus router/dispatcher tracks; ``serve_bench --trace out``
-    opens in Perfetto / chrome://tracing and shows the overlap story:
-    host ``plan``/``dispatch``/``fetch`` spans running UNDER the device
-    track's dispatch windows.  Tracing is opt-in: when ``enabled`` is
-    False every call returns before touching a clock.
+  * ``SpanTracer`` — ``repro:<layer>.<what>`` host spans
+    (``engine.step`` and its ``schedule``/``reserve``/``build``/
+    ``dispatch``/``fetch`` children, ``dispatcher.route``) written as
+    ``jax.profiler.TraceAnnotation``s, so one profiler trace
+    (``Telemetry.profile``) holds the host spans and the device ops
+    they caused on one clock.  Opt-in: disabled, a span is a shared
+    null context.
   * ``JsonlMetricsWriter`` — a periodic snapshot thread appending one
     JSON object per line, for long-running serves.
 
 Cost discipline: counters/gauges are always on (attribute adds on
 host-side ints); histograms observe once per request or per dispatch,
-never per token; lifecycle stamps are per-request dict writes; span
-tracing touches ``time.perf_counter`` only when enabled.  Nothing here
-ever forces a device sync — timestamps are taken at host events the
-engine already passes through.
+never per token; lifecycle stamps are per-request dict writes; a
+disabled span reads no clock.  Nothing here ever forces a device sync —
+timestamps are taken at host events the engine already passes through.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
 from bisect import bisect_left
-from typing import Dict, IO, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, IO, List, Optional, Sequence, Tuple
+
+import jax
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "RequestTrace",
@@ -415,79 +417,31 @@ class TraceBook:
 
 
 # ---------------------------------------------------------------------------
-# Chrome trace_event span timelines
+# host spans in the JAX profiler's trace
 # ---------------------------------------------------------------------------
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 class SpanTracer:
-    """Complete-span ("ph": "X") Chrome trace_event collector.
+    """Host spans written into the JAX profiler's trace.
 
-    Tracks (one ``tid`` each, named via metadata events) are allocated
-    on first use; the convention the serving stack uses is
-    ``replica{i}/host`` (the worker thread: plan/dispatch/fetch spans),
-    ``replica{i}/device`` (dispatch-to-fetch windows — the host-observed
-    envelope of device execution), and ``dispatcher`` (routing).  All
-    timestamps are ``time.perf_counter`` seconds, rebased to the
-    tracer's construction so Perfetto timelines start near zero.
-
-    When ``enabled`` is False every method is a cheap early return —
-    the engine guards its ``perf_counter`` calls on this flag too, so
-    tracing off means tracing free."""
+    ``span(layer, what, **args)`` is a context manager around the work:
+    enabled, it is a ``jax.profiler.TraceAnnotation`` named
+    ``repro:<layer>.<what>`` with ``args`` as its metadata, so the span
+    lands on the profiler's clock beside the device ops of the same
+    trace (``Telemetry.profile``).  The thread that runs the work is
+    the span's track, and spans nest as the ``with`` blocks do.
+    Disabled, it returns one shared null context and reads no clock.
+    An annotation costs its construction even when no profiler runs."""
 
     def __init__(self, enabled: bool = False):
         self.enabled = enabled
-        self._t0 = time.perf_counter()
-        self._events: List[Dict[str, object]] = []
-        self._tids: Dict[str, int] = {}
-        self._lock = threading.Lock()
 
-    def _tid(self, track: str) -> int:
-        tid = self._tids.get(track)
-        if tid is not None:
-            return tid
-        with self._lock:
-            if track not in self._tids:
-                tid = len(self._tids)
-                self._tids[track] = tid
-                self._events.append({
-                    "name": "thread_name", "ph": "M", "pid": 0, "tid": tid,
-                    "ts": 0, "args": {"name": track}})
-            return self._tids[track]
-
-    def span(self, track: str, name: str, t0: float, t1: float,
-             args: Optional[Dict[str, object]] = None) -> None:
-        """One complete span on ``track`` over ``[t0, t1]`` perf_counter
-        seconds.  Spans on one track should be disjoint or properly
-        nested (the Chrome renderer assumes it; the invariant tests
-        enforce it) — callers tracking an async resource serialize their
-        spans (see ``Engine._dev_tail``)."""
+    def span(self, layer: str, what: str, **args):
         if not self.enabled:
-            return
-        self._events.append({
-            "name": name, "ph": "X", "pid": 0, "tid": self._tid(track),
-            "ts": (t0 - self._t0) * 1e6,
-            "dur": max(0.0, (t1 - t0)) * 1e6,
-            "args": args or {}})
-
-    def instant(self, track: str, name: str,
-                t: Optional[float] = None,
-                args: Optional[Dict[str, object]] = None) -> None:
-        if not self.enabled:
-            return
-        t = time.perf_counter() if t is None else t
-        self._events.append({
-            "name": name, "ph": "i", "pid": 0, "tid": self._tid(track),
-            "ts": (t - self._t0) * 1e6, "s": "t", "args": args or {}})
-
-    def events(self) -> List[Dict[str, object]]:
-        return list(self._events)
-
-    def export(self) -> Dict[str, object]:
-        return {"traceEvents": self.events(), "displayTimeUnit": "ms"}
-
-    def write(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.export(), f)
+            return _NO_SPAN
+        return jax.profiler.TraceAnnotation(f"repro:{layer}.{what}", **args)
 
 
 # ---------------------------------------------------------------------------
@@ -508,8 +462,18 @@ class Telemetry:
         self.tracer = tracer or SpanTracer(enabled=trace)
         self.requests = TraceBook(self.registry)
 
-    def write_trace(self, path: str) -> None:
-        self.tracer.write(path)
+    @contextlib.contextmanager
+    def profile(self, log_dir: str):
+        """Profile the enclosed work into ``log_dir`` with the spans on:
+        ``jax.profiler.trace`` writes the ``.xplane.pb`` and a
+        ``perfetto_trace.json.gz`` (open at https://ui.perfetto.dev),
+        host spans and device ops on one clock."""
+        was, self.tracer.enabled = self.tracer.enabled, True
+        try:
+            with jax.profiler.trace(log_dir, create_perfetto_trace=True):
+                yield
+        finally:
+            self.tracer.enabled = was
 
 
 # analysis: single-writer — the controlling thread is the only mutator

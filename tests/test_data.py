@@ -60,3 +60,29 @@ def test_host_loader_latency_simulation():
         assert time.time() - t0 > 0.04     # latency is really applied
     finally:
         loader.close()
+
+
+def test_host_loader_counts_waits_on_an_empty_queue():
+    """The first batch takes the worker 0.3 s to make: ``next`` blocks
+    on the empty queue for about that long, and wait_s counts it."""
+    cfg = DataConfig(kind="lm", vocab_size=10, seq_len=2, global_batch=1)
+    with HostLoader(cfg, prefetch=1, io_latency_s=0.3) as loader:
+        next(loader)
+        assert loader.batches == 1
+        assert loader.wait_s > 0.1
+
+
+def test_host_loader_no_wait_on_a_full_queue():
+    """A batch already in the queue is handed out without waiting:
+    wait_s does not move."""
+    cfg = DataConfig(kind="lm", vocab_size=10, seq_len=2, global_batch=1)
+    with HostLoader(cfg, prefetch=2) as loader:
+        deadline = time.time() + 10.0
+        while loader._q.qsize() < 2 and time.time() < deadline:
+            time.sleep(0.01)
+        assert loader._q.qsize() == 2
+        before = loader.wait_s
+        next(loader)
+        next(loader)
+        assert loader.wait_s == before
+        assert loader.batches == 2

@@ -1,7 +1,7 @@
 """Serving telemetry: registry/histogram unit invariants, request
 lifecycle trace invariants on real engine runs, the jit-compile
-steady-state regression guard, Chrome trace well-formedness, and the
-cluster metrics()/stats back-compat contract.
+steady-state regression guard, the engine's spans in the profiler's
+trace, and the cluster metrics()/stats back-compat contract.
 
 The load-bearing invariants (also property-tested in
 test_telemetry_props.py):
@@ -9,15 +9,19 @@ test_telemetry_props.py):
   * every submitted request reaches exactly ONE terminal event
     (``trace_double_terminals == 0``);
   * TTFT <= e2e (both measured from the same submit stamp);
-  * span timestamps are monotonic and disjoint-or-nested per track;
+  * spans are disjoint-or-nested per thread, and each fetch names
+    the step that dispatched it;
   * after warmup, steady-state serving triggers zero new jit compiles
     at every dispatch depth and under mixed prefill/decode.
 """
+import glob
 import json
+import os
 
 import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.models.model import build_model
 from repro.serve import (Engine, EngineConfig, Request, ServeCluster,
@@ -260,68 +264,124 @@ def test_zero_new_compiles_in_steady_state(tiny_lm, spd):
 
 
 # ---------------------------------------------------------------------------
-# Chrome trace export
+# spans in the profiler's trace
 # ---------------------------------------------------------------------------
 
 
-def _spans_by_track(events):
-    names = {e["tid"]: e["args"]["name"] for e in events if e["ph"] == "M"}
+def _profiled_spans(trace_dir):
+    """``repro:`` host spans of the profile under ``trace_dir``, per
+    thread (one host-plane line each): [(name, start_ns, end_ns,
+    stats)] in start order."""
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
     out = {}
-    for e in events:
-        if e["ph"] == "X":
-            out.setdefault(names[e["tid"]], []).append(e)
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for i, line in enumerate(plane.lines):   # names repeat
+            spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                      dict(e.stats)) for e in line.events
+                     if e.name.startswith("repro:")]
+            if spans:
+                out[f"{plane.name}/{i}"] = sorted(
+                    spans, key=lambda e: (e[1], -e[2]))
     return out
 
 
-def _assert_disjoint_or_nested(spans, eps=0.5):
-    """Chrome's renderer assumes spans on one track are disjoint or
-    properly nested; eps is float slop in microseconds."""
-    spans = sorted(spans, key=lambda e: (e["ts"], -e["dur"]))
+def _assert_nested(spans):
+    """Spans of one thread are disjoint or properly nested."""
     stack = []
-    for e in spans:
-        t0, t1 = e["ts"], e["ts"] + e["dur"]
-        assert e["dur"] >= 0.0
-        while stack and t0 >= stack[-1] - eps:
+    for name, t0, t1, _ in spans:
+        assert t1 >= t0
+        while stack and t0 >= stack[-1]:
             stack.pop()
         if stack:
-            assert t1 <= stack[-1] + eps, "overlapping spans on one track"
+            assert t1 <= stack[-1], f"{name} overlaps its parent"
         stack.append(t1)
+
+
+def _children(spans, parent):
+    """Spans lying inside ``parent`` (same thread)."""
+    _, p0, p1, _ = parent
+    return [s for s in spans if s is not parent and p0 <= s[1]
+            and s[2] <= p1]
 
 
 def test_engine_trace_export_well_formed(tiny_lm, tmp_path):
     cfg, model, params = tiny_lm
     tel = Telemetry(trace=True)
     eng = Engine(model, params, _ecfg(steps_per_dispatch=8), telemetry=tel)
-    eng.run([Request(prompt=r.prompt.copy(),
-                     max_new_tokens=r.max_new_tokens, rid=r.rid)
-             for r in _requests(cfg, 3, 46000)])
-    path = str(tmp_path / "trace.json")
-    tel.write_trace(path)
-    doc = json.load(open(path))
-    events = doc["traceEvents"]
-    assert events
-    for e in events:
-        assert "ph" in e and "ts" in e and "pid" in e and "tid" in e
-    by_track = _spans_by_track(events)
-    assert "replica0/host" in by_track and "replica0/device" in by_track
-    for spans in by_track.values():
-        _assert_disjoint_or_nested(spans)
-    # the host track carries the span vocabulary the README documents
-    host_names = {e["name"].split(":")[0] for e in by_track["replica0/host"]}
-    assert "plan" in host_names and "dispatch" in host_names \
-        and "fetch" in host_names
+    eng.warmup()
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run([Request(prompt=r.prompt.copy(),
+                         max_new_tokens=r.max_new_tokens, rid=r.rid)
+                 for r in _requests(cfg, 3, 46000)])
+    by_thread = _profiled_spans(str(tmp_path))
+    assert len(by_thread) == 1            # the engine ran on this thread
+    (spans,) = by_thread.values()
+    _assert_nested(spans)
+    names = {s[0] for s in spans}
+    assert {"repro:engine.step", "repro:engine.schedule",
+            "repro:engine.reserve", "repro:engine.build",
+            "repro:engine.dispatch", "repro:engine.fetch"} <= names
+    steps = [s for s in spans if s[0] == "repro:engine.step"]
+    assert [s[3]["step"] for s in steps] == list(range(len(steps)))
+    # every span lies inside an engine.step
+    assert sum(len(_children(spans, s)) for s in steps) \
+        == len(spans) - len(steps)
+    # the phases the decode loop and the fused step dispatch under
+    phases = {s[3]["phase"] for s in spans
+              if s[0] == "repro:engine.dispatch"}
+    assert {"loop", "prefill"} <= phases, phases
+    # every fetch names the step that dispatched what it fetched
+    dispatched = {s[3]["step"] for s in steps
+                  if any(c[0] == "repro:engine.dispatch"
+                         for c in _children(spans, s))}
+    fetched = [s[3]["step"] for s in spans if s[0] == "repro:engine.fetch"]
+    assert fetched and set(fetched) <= dispatched
+    assert len(fetched) == len(set(fetched))  # each dispatch fetched once
+    assert tel.registry.snapshot()["counters"][
+        "engine_host_syncs{arch=%s,replica=0}" % cfg.name] == len(fetched)
 
 
-def test_tracing_off_is_free(tiny_lm):
-    """With tracing off (the default) no span events accumulate — the
-    enabled flag gates every collection point."""
+def test_tracing_off_is_free(tiny_lm, tmp_path, monkeypatch):
+    """With tracing off (the default) no annotation is created: every
+    span is the one shared null context."""
+    cfg, model, params = tiny_lm
+    made = []
+    real = jax.profiler.TraceAnnotation
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda *a, **k: made.append(a) or real(*a, **k))
+    tel = Telemetry()
+    eng = Engine(model, params, _ecfg(), telemetry=tel)
+    eng.warmup()
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run([Request(prompt=r.prompt.copy(),
+                         max_new_tokens=r.max_new_tokens, rid=r.rid)
+                 for r in _requests(cfg, 2, 47000)])
+    assert made == []
+    assert _profiled_spans(str(tmp_path)) == {}
+    assert tel.tracer.span("engine", "step") \
+        is tel.tracer.span("engine", "fetch")
+
+
+def test_profile_writes_spans_and_perfetto(tiny_lm, tmp_path):
+    """``Telemetry.profile`` turns the spans on for its block only and
+    writes the Perfetto file beside the trace."""
     cfg, model, params = tiny_lm
     tel = Telemetry()
     eng = Engine(model, params, _ecfg(), telemetry=tel)
-    eng.run([Request(prompt=r.prompt.copy(),
-                     max_new_tokens=r.max_new_tokens, rid=r.rid)
-             for r in _requests(cfg, 2, 47000)])
-    assert tel.tracer.events() == []
+    eng.warmup()
+    with tel.profile(str(tmp_path)):
+        assert tel.tracer.enabled
+        eng.run([Request(prompt=r.prompt.copy(),
+                         max_new_tokens=r.max_new_tokens, rid=r.rid)
+                 for r in _requests(cfg, 2, 47500)])
+    assert not tel.tracer.enabled
+    (spans,) = _profiled_spans(str(tmp_path)).values()
+    assert "repro:engine.step" in {s[0] for s in spans}
+    assert glob.glob(str(tmp_path / "**" / "perfetto_trace.json.gz"),
+                     recursive=True)
 
 
 # ---------------------------------------------------------------------------
@@ -334,9 +394,11 @@ def test_cluster_metrics_per_replica_aggregation(tiny_lm, tmp_path):
     cl = ServeCluster.for_replicas(model, params, _ecfg(),
                                    num_replicas=2, trace=True)
     reqs = _requests(cfg, 6, 48000)
-    res = cl.run([Request(prompt=r.prompt.copy(),
-                          max_new_tokens=r.max_new_tokens, rid=r.rid)
-                  for r in reqs])
+    cl.warmup()
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        res = cl.run([Request(prompt=r.prompt.copy(),
+                              max_new_tokens=r.max_new_tokens, rid=r.rid)
+                      for r in reqs])
     assert len(res) == len(reqs)
     m = cl.metrics()
     assert sorted(m["per_replica"]) == [0, 1]
@@ -357,12 +419,19 @@ def test_cluster_metrics_per_replica_aggregation(tiny_lm, tmp_path):
         assert tr.stamps["submit"] <= tr.stamps["route"] \
             <= tr.stamps["admit"]
         assert tr.replica in (0, 1)
-    # trace: one host+device track pair per replica + dispatcher track
-    cl.write_trace(str(tmp_path / "cluster_trace.json"))
-    doc = json.load(open(tmp_path / "cluster_trace.json"))
-    tracks = {e["args"]["name"] for e in doc["traceEvents"]
-              if e["ph"] == "M"}
-    assert {"replica0/host", "replica1/host", "dispatcher"} <= tracks
+    # trace: each replica's engine spans on its own worker thread, the
+    # routing spans on the submitting thread
+    by_thread = _profiled_spans(str(tmp_path / "trace"))
+    names = {t: {s[0] for s in spans} for t, spans in by_thread.items()}
+    route = [t for t, n in names.items() if "repro:dispatcher.route" in n]
+    workers = [t for t, n in names.items() if "repro:engine.step" in n]
+    assert len(route) == 1 and len(workers) == 2
+    assert route[0] not in workers
+    routed = [s[3]["rid"] for s in by_thread[route[0]]
+              if s[0] == "repro:dispatcher.route"]
+    assert sorted(routed) == sorted(r.rid for r in reqs)
+    for spans in by_thread.values():
+        _assert_nested(spans)
     # metrics JSON export round-trips
     cl.write_metrics(str(tmp_path / "metrics.json"))
     exported = json.load(open(tmp_path / "metrics.json"))

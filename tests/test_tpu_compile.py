@@ -5,14 +5,16 @@ layouts and 1-D values that the TPU compiler refuses.  These tests
 compile each kernel-module entry point with ``interpret=False`` for a
 described (not attached) ``v5e:2x2`` chip, at the padded shapes
 ``repro.kernels.ops`` produces for real model widths, and check that the
-executable holds the compiled kernel (``tpu_custom_call``).  Nothing
-runs; a compile that passes here is not a chip run.
+executable holds the compiled kernel (``tpu_custom_call``), named after
+the kernel as the device trace will show it.  Nothing runs; a compile
+that passes here is not a chip run.
 
 The topology is described inside a fixture (never at import), so every
 pytest worker collects the same tests and only the worker given this
 file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -145,10 +147,55 @@ CASES = {
 }
 
 
+# case -> the kernel name its pallas_call carries
+KERNEL_OF = {
+    "decode_view_attend": "decode_view_attend",
+    "flash_decode_paged/decode": "flash_decode_paged",
+    "flash_decode_paged/prefill_chunk": "flash_decode_paged",
+    "mla_decode_views": "mla_views_attend",
+    "mla_decode_paged": "mla_paged_attend",
+    "slot_gather/state": "slot_gather_rows",
+    "slot_scatter/state": "slot_scatter_rows",
+    "slot_gather/conv": "slot_gather_rows",
+    "sample/greedy": "greedy_sample",
+    "sample/gumbel": "gumbel_sample",
+    "sample/top_k": "topk_gumbel_sample",
+    "fused_sgd_update": "fused_sgd_update",
+    "flash_attention": "flash_attention",
+    "ssd_chunk": "ssd_chunk",
+}
+
+
+@pytest.fixture(scope="module")
+def compiled_text(one_chip):
+    """The v5e-compiled HLO text of a case, compiled once per module."""
+    texts = {}
+
+    def get(name):
+        if name not in texts:
+            fn, shapes = CASES[name]
+            args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+                    for s, dt in shapes]
+            texts[name] = jax.jit(fn).lower(*args).compile().as_text()
+        return texts[name]
+
+    return get
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_kernel_compiles_for_v5e(one_chip, name):
-    fn, shapes = CASES[name]
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
-            for s, dt in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_kernel_compiles_for_v5e(compiled_text, name):
+    assert "tpu_custom_call" in compiled_text(name)
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_carries_its_name(compiled_text, name):
+    """The Mosaic custom call is the instruction the device trace names
+    the kernel by: it is called after the kernel, and its op_name ends
+    in the kernel's name."""
+    kernel = KERNEL_OF[name]
+    calls = [line for line in compiled_text(name).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert re.search(r"%" + kernel + r"(\.\d+)? = ", line), line[:120]
+        assert f'/{kernel}/pallas_call"' in line
