@@ -199,6 +199,12 @@ def _check_blocks(where: str, grid_mapping) -> List[Finding]:
     return out
 
 
+def _is_semaphore(dtype) -> bool:
+    from jax.experimental.pallas import tpu as pltpu
+    return any(jnp.issubdtype(dtype, t)
+               for t in (pltpu.semaphore, pltpu.dma_semaphore))
+
+
 def _check_scratch(where: str, kernel_jaxpr, num_scratch: int
                    ) -> List[Finding]:
     out: List[Finding] = []
@@ -207,6 +213,8 @@ def _check_scratch(where: str, kernel_jaxpr, num_scratch: int
     for j, var in enumerate(kernel_jaxpr.invars[-num_scratch:]):
         aval = var.aval
         shape = getattr(aval, "shape", ())
+        if _is_semaphore(aval.dtype):       # holds no tile
+            continue
         dt = jnp.dtype(aval.dtype)
         space = _mem_space(var)
         size = 1

@@ -200,7 +200,9 @@ def flash_decode_paged(q, k_pool, v_pool, block_tables, pos, *,
     """Paged decode/prefill-chunk attention for repro.serve:
     q (B,C,H,hd) — C query tokens per row; pools (nb, bs, KV, hd);
     block_tables (B,NB); pos (B,) absolute position of each row's first
-    query -> (B,C,H,hd).
+    query -> (B,C,H,hd).  The kernel's work follows the call's query
+    runs and their live pages (``flash_decode.query_tiles``, computed
+    per shard from the replicated tables and positions).
 
     When hd % 128 != 0 this pads the ENTIRE pools on every call —
     O(pool) per layer per step.  A production TPU caller should

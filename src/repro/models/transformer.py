@@ -166,10 +166,18 @@ def apply_run(rp, h, cfg, kind: str, ffn: str, *, positions, cache=None,
               cache_len=0):
     """Scan h through a stacked run.  cache (if given) has leading L axis."""
     use_cache = cache is not None
+    # every layer of a run shares its block tables: close over one copy
+    # so what a kernel derives from them is loop-invariant
+    tables = None
+    if use_cache and "block_tables" in cache:
+        tables = cache["block_tables"][0]
+        cache = {k: v for k, v in cache.items() if k != "block_tables"}
 
     def body(carry, xs):
         if use_cache:
             lp, lc = xs
+            if tables is not None:
+                lc = dict(lc, block_tables=tables)
         else:
             lp, lc = xs, None
         hh, c, aux = apply_layer(lp, carry, cfg, kind, ffn,

@@ -7,12 +7,15 @@ mixed prefill+decode rows:
     (bucket, 1) shape, prefill-only steps use chunk-wide rows, and
     mixed steps split prefill chunks into one width-1 row per prompt
     token (later chunk tokens attend their siblings' KV because every
-    row's scatter lands before any row's gather inside the call) so
-    the step costs exactly the token-positions of the legacy two-call
-    layout instead of padding decode rows to the chunk width; a
-    per-row ``valid_len`` input routes padded/inactive rows' KV writes
-    to the trash block, so a stale row can never clobber a live
-    sequence's blocks;
+    row's scatter lands before any row's gather inside the call)
+    instead of padding decode rows to the chunk width.  A chunk's
+    width-1 rows sit in consecutive rows with one block table and
+    consecutive positions, so the paged attend (``flash_decode_paged``)
+    reads them as one query run: its work follows the step's runs and
+    their live pages, not rows x table length.  A per-row
+    ``valid_len`` input routes padded/inactive rows' KV writes to the
+    trash block, so a stale row can never clobber a live sequence's
+    blocks;
   * sampling happens on device inside the call — greedy argmax AND
     temperature/top-k (per-row keys derived fold_in(rid, position), so
     the draw is identical at any dispatch depth and across preemption
@@ -77,11 +80,16 @@ from repro.serve.telemetry import LatencyHists, MetricsRegistry, Telemetry
 
 # the flat integer counters in ``metrics_snapshot()["counters"]`` (plus
 # ``jit_compiles``); each is a registry counter labeled with this
-# engine's replica/arch
+# engine's replica/arch.  ``paged_query_runs`` / ``paged_kv_pages``: the
+# query runs of each fused step whose attends run flash_decode_paged,
+# and the pages its tiles visit (``flash_decode.query_tiles``, at the
+# widest window), summed over dispatches; over rows x blocks_per_seq
+# per dispatch they give the share of a whole-table walk left
 _STAT_KEYS = ("steps", "decode_steps", "decode_slot_steps",
               "decode_active_slot_steps", "prefill_tokens",
               "generated_tokens", "preemptions", "faulted", "model_calls",
-              "host_syncs", "loop_dispatches", "loop_truncations")
+              "host_syncs", "loop_dispatches", "loop_truncations",
+              "paged_query_runs", "paged_kv_pages")
 
 _DISPATCH_PHASES = ("prefill", "decode", "mixed", "loop")
 
@@ -181,10 +189,11 @@ class EngineConfig:
         """Row counts for fused steps that carry BOTH decode rows and
         prefill work.  Prefill chunks are split into width-1 rows (one
         row per prompt token, all in the same call — later tokens attend
-        siblings' KV written earlier in the call), so a mixed step costs
-        exactly the same token-positions as the unfused
-        prefill-call-plus-decode-call layout instead of padding every
-        decode row to the chunk width."""
+        siblings' KV written earlier in the call) instead of padding
+        every decode row to the chunk width.  The per-token layers cost
+        one row per token; the paged attend costs each query run (a
+        decode row, or a chunk's consecutive rows) over its live pages,
+        whatever the bucket's padding rows add (one page each)."""
         full = self.max_batch + self.prefill_token_budget
         half = self.max_batch + max(self.prefill_chunk,
                                     self.prefill_token_budget // 2)
@@ -311,6 +320,15 @@ class Engine:
                 "the N-step on-device decode loop requires the fused "
                 "step (device-side sampling + slot buffer)")
         self.model = model
+        # the fused steps' attends run flash_decode_paged: its query
+        # tiles feed the paged_* counters.  Kernel modules (and Pallas)
+        # load only when used, as in models/
+        self._query_tiles = None
+        if model.cfg.attn_impl == "pallas" and any(
+                op.endswith("/flash_decode_paged")
+                for _, op in self.spec.kernel_spec):
+            from repro.kernels.flash_decode import query_tiles
+            self._query_tiles = query_tiles
         # telemetry: one bundle per frontend (ServeCluster shares its
         # bundle across replicas; a standalone engine builds its own).
         # Counters/gauges are always on; span tracing only runs when the
@@ -926,14 +944,17 @@ class Engine:
         #                   fewer rows means fewer per-row KV-pool
         #                   gathers;
         #   mixed        -> (bucket, 1): width-1 rows with prefill chunks
-        #                   SPLIT into one row per token.  This costs
-        #                   exactly the token-positions of the legacy
-        #                   prefill-call-plus-decode-call pair (instead
-        #                   of padding every decode row to the chunk
-        #                   width) while paying ONE dispatch.  Chunk
-        #                   token i attends its siblings' KV because
-        #                   every row's scatter lands before any row's
-        #                   gather within the call.  Slot-state families
+        #                   SPLIT into one row per token, in consecutive
+        #                   rows with one table and consecutive
+        #                   positions, so the paged attend reads a
+        #                   chunk as one query run over its live pages
+        #                   (and each decode row as a run of one), not
+        #                   each row over the whole table; ONE dispatch
+        #                   instead of padding every decode row to the
+        #                   chunk width.  Chunk token i attends its
+        #                   siblings' KV because every row's scatter
+        #                   lands before any row's gather within the
+        #                   call.  Slot-state families
         #                   (ssm/rglru) can't split — a token's recurrent
         #                   state depends on the previous token *within
         #                   the call* — so their mixed layout keeps
@@ -1022,6 +1043,12 @@ class Engine:
             phase = ("decode" if n_pre == 0
                      else "prefill" if n_dec == 0 else "mixed")
             tables = self.kv.table_array(rids)
+            if self._query_tiles is not None:
+                qt = self._query_tiles(tables, pos, width,
+                                       block_size=cfg.block_size,
+                                       window=self.spec.reclaim_window)
+                self._m.paged_query_runs.inc(int(qt.n_runs))
+                self._m.paged_kv_pages.inc(int(qt.n_pages))
         with span("engine", "dispatch", phase=phase, rows=rows, width=width):
             t0 = time.perf_counter()
             toks = self._dispatch(tokens, meta, tables)

@@ -127,6 +127,25 @@ def test_kc005_fires_on_bad_vmem_scratch():
     assert len(fs) == 3
 
 
+def test_kc005_skips_semaphores_but_sizes_vmem_beside_them():
+    def kernel(x_ref, o_ref, dma, sem, vlane):
+        vlane[...] = x_ref[...][:, :64]          # minor 64, not 128
+        o_ref[...] = x_ref[...] * 2
+
+    def op(x):
+        return pl.pallas_call(
+            kernel, out_shape=_sds((8, 128)),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SemaphoreType.REGULAR(()),
+                            pltpu.VMEM((8, 64), f32)],
+            interpret=True)(x)
+
+    fs = [f for f in kc.check_traced("fixture/sems", op, (_sds((8, 128)),))
+          if f.obj.startswith("scratch")]
+    assert [(f.rule, f.obj.split(":")[0]) for f in fs] == [
+        ("KC005", "scratch[2]")]
+
+
 def test_kc006_fires_on_float_prefetch_and_oversized_smem():
     def kernel(p_ref, x_ref, o_ref):
         o_ref[...] = x_ref[...] + p_ref[0]

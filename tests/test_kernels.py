@@ -1,5 +1,7 @@
 """Pallas kernel sweeps: shapes x dtypes, assert_allclose against the
 ref.py pure-jnp oracles (interpret mode on CPU; TPU is the target)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -154,34 +156,169 @@ def test_flash_decode_equals_model_decode_attention():
 # paged flash decode (repro.serve block pools)
 # ---------------------------------------------------------------------------
 
+def _random_tables(rng, nb, b, nb_seq, c, bs):
+    """Disjoint non-trash physical blocks per row, shuffled, and any
+    position at which the row's c queries fit."""
+    perm = rng.permutation(np.arange(1, nb))[:b * nb_seq]
+    pos = rng.integers(0, nb_seq * bs - c + 1, (b,))
+    return perm.reshape(b, nb_seq), pos
+
+
+def _paged_layout(layout, rng, nb, bs, b, c, nb_seq, window):
+    """(block tables, positions, physical blocks no query may read) of
+    one sweep case.  Every layout but "random" is hand-built around the
+    query runs the kernel cuts the call into."""
+    bt, pos = _random_tables(rng, nb, b, nb_seq, c, bs)
+    dead = []                   # blocks no query may read
+    if layout == "mixed":
+        # width-1 mixed step: 3 decode rows, then request A's prompt
+        # tokens 5..11 and request B's 0..3, one row per token
+        pos[:3] = [3, nb_seq * bs - 1, 17]
+        bt[3:10], pos[3:10] = bt[3], np.arange(5, 12)
+        bt[10:14], pos[10:14] = bt[10], np.arange(4)
+    elif layout == "long_run":
+        # one run of b - 2 tokens, longer than a query tile, crossing
+        # pages; a decode row on each side
+        bt[1:b - 1], pos[1:b - 1] = bt[1], np.arange(3, b + 1)
+    elif layout == "same_table_gap":
+        # identical tables, positions 5, 9, 10, 10: three runs
+        bt[:4], pos[:4] = bt[0], [5, 9, 10, 10]
+    elif layout == "padded":
+        # padding rows: position 0, every entry the trash block
+        bt[1::2], pos[1::2] = 0, 0
+    elif layout == "full_table":
+        pos[0] = nb_seq * bs - c
+    elif layout == "window_trash":
+        # pages wholly before the first query's window are reclaimed to
+        # the trash block, which the kernel must not read
+        pos[:] = nb_seq * bs - c - rng.integers(0, bs, (b,))
+        for r in range(b):
+            bt[r, :max(0, (int(pos[r]) - window + 1) // bs)] = 0
+        dead = [0]
+    elif layout == "chunk_rows":
+        # chunk-wide rows: rows 0-2 are one request's consecutive chunks
+        bt[:3], pos[:3] = bt[0], np.arange(3) * c + 2
+    return jnp.asarray(bt, jnp.int32), jnp.asarray(pos, jnp.int32), dead
+
+
 PAGED_SHAPES = [
-    # nb, bs, kv, hd, b, c, h, nb_seq, window
-    (16, 8, 2, 64, 3, 1, 4, 4, 0),
-    (9, 16, 1, 128, 2, 1, 4, 4, 0),
-    (32, 8, 4, 96, 2, 1, 8, 6, 20),   # GQA + sliding window + hd pad
-    (16, 8, 2, 64, 3, 4, 4, 4, 0),    # chunked queries (fused prefill)
-    (32, 8, 4, 96, 2, 8, 8, 6, 20),   # chunk + window + hd pad
+    # nb, bs, kv, hd, b, c, h, nb_seq, window, layout
+    (16, 8, 2, 64, 3, 1, 4, 4, 0, "random"),
+    (9, 16, 1, 128, 2, 1, 4, 4, 0, "random"),
+    (32, 8, 4, 96, 2, 1, 8, 6, 20, "random"),   # GQA + window + hd pad
+    (16, 8, 2, 64, 3, 4, 4, 4, 0, "random"),    # chunked queries (prefill)
+    (32, 8, 4, 96, 2, 8, 8, 6, 20, "random"),   # chunk + window + hd pad
+    (64, 8, 2, 64, 16, 1, 4, 3, 0, "mixed"),    # decode rows, two prompts
+    (2449, 8, 2, 64, 136, 1, 4, 18, 0, "long_run"),
+    (20, 8, 2, 64, 5, 1, 4, 3, 0, "same_table_gap"),
+    (20, 8, 2, 64, 6, 1, 4, 3, 0, "padded"),
+    (12, 16, 2, 128, 3, 1, 4, 3, 0, "full_table"),
+    (40, 8, 2, 64, 4, 2, 4, 8, 12, "window_trash"),
+    (40, 8, 2, 64, 4, 8, 4, 8, 0, "chunk_rows"),
 ]
 
 
 @pytest.mark.parametrize("case", PAGED_SHAPES)
 @pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
 def test_flash_decode_paged_sweep(case, dt):
-    nb, bs, kv, hd, b, c, h, nb_seq, window = case
+    nb, bs, kv, hd, b, c, h, nb_seq, window, layout = case
     ks = jax.random.split(jax.random.key(nb + hd + c), 3)
     q = jax.random.normal(ks[0], (b, c, h, hd), jnp.float32).astype(dt)
     kp = jax.random.normal(ks[1], (nb, bs, kv, hd), jnp.float32).astype(dt)
     vp = jax.random.normal(ks[2], (nb, bs, kv, hd), jnp.float32).astype(dt)
-    rng = np.random.default_rng(nb)
-    # disjoint non-trash physical blocks per sequence, shuffled
-    perm = rng.permutation(np.arange(1, nb))[:b * nb_seq]
-    bt = jnp.asarray(perm.reshape(b, nb_seq), jnp.int32)
-    # position of each row's first query; the row's c queries must fit
-    pos = jnp.asarray(rng.integers(0, nb_seq * bs - c + 1, (b,)), jnp.int32)
-    o1 = ops.flash_decode_paged(q, kp, vp, bt, pos, window=window)
+    bt, pos, dead = _paged_layout(layout, np.random.default_rng(nb), nb, bs,
+                                  b, c, nb_seq, window)
     o2 = ref.flash_decode_paged(q, kp, vp, bt, pos, window=window)
+    if dead:
+        # blocks no query may read hold NaN: one read spoils the output
+        dead = np.array(dead)
+        kp, vp = kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan)
+    o1 = ops.flash_decode_paged(q, kp, vp, bt, pos, window=window)
     assert o1.shape == (b, c, h, hd)
     np.testing.assert_allclose(np.float32(o1), np.float32(o2), **_tol(dt))
+
+
+@pytest.mark.parametrize("xp", [np, jnp])
+@pytest.mark.parametrize("window", [0, 6])
+def test_query_tiles_hand_built_mixed_layout(xp, window):
+    """Runs, tiles and live pages of a width-1 mixed step (block 4, 8
+    pages a table): decode rows at positions 20 and 3, one prompt's
+    tokens 5-9 on one table, two padding rows."""
+    from repro.kernels import flash_decode as fd
+    ta, tb, tc = np.arange(1, 9), np.arange(9, 17), np.arange(17, 25)
+    pad = np.zeros(8, int)
+    bt = np.stack([ta, tb] + [tc] * 5 + [pad, pad])
+    pos = np.array([20, 3, 5, 6, 7, 8, 9, 0, 0])
+    qt = fd.query_tiles(bt, pos, 1, block_size=4, window=window, xp=xp)
+    k = int(qt.n_tiles)
+    assert (k, int(qt.n_runs)) == (5, 5)
+    assert np.asarray(qt.start)[:k].tolist() == [0, 1, 2, 7, 8]
+    assert np.asarray(qt.length)[:k].tolist() == [1, 1, 5, 1, 1]
+    assert np.asarray(qt.hi)[:k].tolist() == [5, 0, 2, 0, 0]
+    # with a window of 6, row 0 (position 20) first sees position 15
+    lo = [3, 0, 0, 0, 0] if window else [0] * 5
+    assert np.asarray(qt.lo)[:k].tolist() == lo
+    assert int(qt.n_pages) == (9 if window else 12)
+    if xp is jnp:               # padded to one tile per token, length 0
+        assert np.asarray(qt.length)[k:].tolist() == [0] * (9 - k)
+
+
+def test_query_tiles_split_a_run_longer_than_a_tile():
+    """Three 64-token chunk rows of one request make one 192-token run;
+    grid blocks of two rows cut it at the 128-token tile."""
+    from repro.kernels import flash_decode as fd
+    bt = np.tile(np.arange(1, 17), (3, 1))
+    assert fd.tile_shape(3, 64) == (2, 128)
+    qt = fd.query_tiles(bt, np.array([0, 64, 128]), 64, block_size=16)
+    assert (int(qt.n_runs), int(qt.n_tiles)) == (1, 2)
+    assert qt.start.tolist() == [0, 128] and qt.length.tolist() == [128, 64]
+    assert qt.hi.tolist() == [7, 11] and int(qt.n_pages) == 8 + 12
+    assert qt.block_first.tolist() == [0, 1, 2]
+
+
+def test_query_tiles_cut_runs_at_block_edges():
+    """Width-1 rows: 120 padding rows, then one request's 16 prompt
+    tokens in rows 120-135, which the 128-row grid blocks cut in two; a
+    100-token chunk row (a block of its own) tiles as 96 + 4 tokens."""
+    from repro.kernels import flash_decode as fd
+    bt = np.zeros((136, 8), int)
+    bt[120:] = np.arange(1, 9)
+    pos = np.zeros(136, int)
+    pos[120:] = np.arange(16)
+    assert fd.tile_shape(136, 1) == (128, 128)
+    qt = fd.query_tiles(bt, pos, 1, block_size=4)
+    k = int(qt.n_tiles)
+    assert (k, int(qt.n_runs)) == (122, 121)
+    assert qt.start[-2:].tolist() == [120, 128]
+    assert qt.length[-2:].tolist() == [8, 8]
+    assert qt.hi[-2:].tolist() == [1, 3] and int(qt.n_pages) == 120 + 2 + 4
+    assert qt.block_first.tolist() == [0, 121, 122]
+    assert fd.tile_shape(2, 100) == (1, 96)
+    qt = fd.query_tiles(bt[:2], np.array([0, 100]), 100, block_size=16)
+    assert (int(qt.n_runs), int(qt.n_tiles)) == (1, 4)
+    assert qt.start.tolist() == [0, 96, 100, 196]
+    assert qt.length.tolist() == [96, 4, 96, 4]
+
+
+@pytest.mark.parametrize("rows,heads,kv,table,what", [
+    (544, 112, 8, 256, "VMEM"),          # 128-token tiles of 112 heads
+    (2304, 32, 8, 64, "scalar memory"),  # tables over the 1 MiB of SMEM
+])
+def test_flash_decode_paged_refuses_what_cannot_fit(rows, heads, kv, table,
+                                                    what):
+    """VMEM follows the tile and the heads, the block tables sit in
+    scalar memory: a call past either is refused before it is traced."""
+    from repro.kernels import flash_decode as fd
+    bf = jnp.bfloat16
+    args = (jax.ShapeDtypeStruct((rows, 1, heads, 128), bf),
+            jax.ShapeDtypeStruct((4097, 16, kv, 128), bf),
+            jax.ShapeDtypeStruct((4097, 16, kv, 128), bf),
+            jax.ShapeDtypeStruct((rows, table), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32))
+    with pytest.raises(ValueError, match=what):
+        jax.eval_shape(functools.partial(fd.flash_decode_paged_bhd,
+                                         interpret=True), *args)
+    assert fd.tile_shape(rows, 1) == (128, 128)
 
 
 def test_flash_decode_paged_matches_contiguous():

@@ -195,6 +195,61 @@ def test_engine_counters_snapshot_contract(tiny_lm):
     assert s["steps"] > 0
 
 
+def _hand_count(tables, pos, width, block_size):
+    """Query runs and visited pages of one dispatch, token by token: a
+    token continues the run when its row's table is the previous
+    token's and its position the next one; a tile ends with its run,
+    at a grid block's edge or at ``tile`` tokens, and visits pages 0 ..
+    its last // block."""
+    from repro.kernels.flash_decode import tile_shape
+    block_rows, tile = tile_shape(tables.shape[0], width)
+    runs, pages, prev, n, last, t = 0, 0, None, 0, 0, 0
+    for r in range(tables.shape[0]):
+        for j in range(width):
+            cur = (tuple(tables[r]), int(pos[r]) + j)
+            same = prev is not None and cur[0] == prev[0] \
+                and cur[1] == prev[1] + 1
+            if not same or n == tile or t % (block_rows * width) == 0:
+                if prev is not None:
+                    pages += min(last // block_size, tables.shape[1] - 1) + 1
+                runs += not same
+                n = 0
+            prev, last, n, t = cur, cur[1], n + 1, t + 1
+    return runs, pages + min(last // block_size, tables.shape[1] - 1) + 1
+
+
+def test_paged_run_counters_match_hand_count(tiny_lm):
+    """paged_query_runs / paged_kv_pages equal a token-by-token count of
+    every fused dispatch's layout (prefill, mixed and decode steps)."""
+    cfg, _, _ = tiny_lm
+    cfg = cfg.replace(attn_impl="pallas")
+    model = build_model(cfg)
+    ecfg = _ecfg()
+    eng = Engine(model, model.init(jax.random.key(0)), ecfg)
+    seen = []
+
+    class Record:
+        def __init__(self, fn):
+            self.fn = fn
+
+        def __call__(self, *args):
+            tokens, tables, meta = (np.array(a) for a in args[3:])
+            seen.append(_hand_count(tables, meta[0], tokens.shape[1],
+                                    ecfg.block_size) + (tokens.size,))
+            return self.fn(*args)
+
+        def __getattr__(self, name):
+            return getattr(self.fn, name)
+
+    eng._step_fn = Record(eng._step_fn)
+    eng.run(_requests(cfg, 5, 44000, pmax=40))
+    c = eng.metrics_snapshot()["counters"]
+    runs, pages, tokens = (sum(x) for x in zip(*seen))
+    assert len(seen) == c["model_calls"] > 0
+    assert (c["paged_query_runs"], c["paged_kv_pages"]) == (runs, pages)
+    assert runs < tokens        # prompt tokens share runs
+
+
 def test_kv_and_scheduler_gauges_settle_to_idle(tiny_lm):
     cfg, model, params = tiny_lm
     tel = Telemetry()

@@ -82,6 +82,20 @@ CASES = {
             q, k, v, bt, p, interpret=False),
         ((1, 64, 12, 128), bf16), ((NB, BS, 2, 128), bf16),
         ((NB, BS, 2, 128), bf16), ((1, CTX // BS), i32), ((1,), i32)),
+    # the serve cell's mixed step: 32 decode rows and 512 prompt tokens
+    # as width-1 rows, 256-block tables (max_seq_len 4096), 4097 blocks
+    "flash_decode_paged/mixed": _case(
+        lambda q, k, v, bt, p: fd.flash_decode_paged_bhd(
+            q, k, v, bt, p, interpret=False),
+        ((544, 1, 12, 128), bf16), ((4097, BS, 2, 128), bf16),
+        ((4097, BS, 2, 128), bf16), ((544, 256), i32), ((544,), i32)),
+    # recurrentgemma-2b's local attention (10 query / 1 kv head of 256,
+    # window 2048) at a mixed step of 288 width-1 rows
+    "flash_decode_paged/window": _case(
+        lambda q, k, v, bt, p: fd.flash_decode_paged_bhd(
+            q, k, v, bt, p, window=2048, interpret=False),
+        ((288, 1, 10, 256), bf16), ((4097, BS, 1, 256), bf16),
+        ((4097, BS, 1, 256), bf16), ((288, 256), i32), ((288,), i32)),
     # DeepSeek-V3 absorbed MLA: kv_lora 512, rope 64 (lane-padded to
     # 128), 128 heads
     "mla_decode_views": _case(
@@ -152,6 +166,8 @@ KERNEL_OF = {
     "decode_view_attend": "decode_view_attend",
     "flash_decode_paged/decode": "flash_decode_paged",
     "flash_decode_paged/prefill_chunk": "flash_decode_paged",
+    "flash_decode_paged/mixed": "flash_decode_paged",
+    "flash_decode_paged/window": "flash_decode_paged",
     "mla_decode_views": "mla_views_attend",
     "mla_decode_paged": "mla_paged_attend",
     "slot_gather/state": "slot_gather_rows",
