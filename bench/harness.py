@@ -3,16 +3,18 @@
 Everything a cell needs is found by name:
 
     BENCHMARK.json            the cell: configuration, traffic, chips
-    bench/configs/<c>.json    model, dtypes, trainer or engine settings
+    bench/configs/<c>.json    model, its family, dtypes, trainer or
+                              engine settings
+    bench/reference/<f>.py    the family: layouts, reference, counts
     bench/traffic/<t>.json    the job (training) or mix (serving)
     bench/limits/<cell>.json  the limits of the correctness comparison
     bench/metrics/<m>.py      one reader per metric: read(record) -> value
 
 A cell module (``train_cell`` or ``serve_cell``, chosen by the
 traffic's ``kind``) sets the cell up, measures its window, checks what
-the window produced against ``bench/reference``, and returns a record;
-the readers turn the record into the metrics that BENCHMARK.json lists
-for the cell.
+the window produced against the family's reference, and returns a
+record; the readers turn the record into the metrics that BENCHMARK.json
+lists for the cell.
 """
 from __future__ import annotations
 

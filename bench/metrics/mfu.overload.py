@@ -1,6 +1,7 @@
 """Model operations of the dispatches in the traced stretch (layers per
 processed token, attention over live context, head per emitted token;
-bench/flops.py) over its length times peak."""
+the family's ``serve_flops``, which ``serve_cell`` applies) over its
+length times peak."""
 
 
 def read(rec):

@@ -1,18 +1,27 @@
 """Where the benchmark meets the system under test (``src/repro``).
 
-Nests the benchmark's seeded weights (``reference/weights.py``) into the
-program's parameter tree and names the program's leaves back in the
-benchmark's layout.  Nothing here computes: it only moves arrays.
+A configuration file names its model family (``"family": "<name>"``);
+``family`` returns ``bench/reference/<name>.py``, which holds all that
+depends on the family: the published sizes, the seeded weights in the
+benchmark's own layout, the mapping to and from the program's parameter
+tree, the float32 reference and the operation counts.  The benchmark
+layout is one flat dict, leaf name -> array, whatever names the family
+gives its leaves.  Everything here holds for every family.
 """
 from __future__ import annotations
 
+import importlib
 import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
 
-_ATTN = ("wq", "bq", "wk", "bk", "wv", "bv", "wo")
-_MLP = ("w_gate", "w_up", "w_down")
+# the names every family module exports; its docstring says what each is
+INTERFACE = ("PUBLISHED", "check_published", "dims",
+             "shapes", "generate", "make_weights",
+             "to_program", "from_program",
+             "train_steps", "leaf_norms", "logits_at",
+             "train_flops_per_token", "serve_flops", "attend_cost")
 
 
 def import_program():
@@ -24,64 +33,44 @@ def import_program():
     import repro  # noqa: F401
 
 
-# published config.json key -> the program's ModelConfig field
-PUBLISHED = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-             "num_attention_heads": "num_heads",
-             "num_key_value_heads": "num_kv_heads",
-             "num_hidden_layers": "num_layers", "vocab_size": "vocab_size",
-             "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-             "tie_word_embeddings": "tie_embeddings"}
+def family(spec: dict):
+    """The family module that ``spec`` names under ``"family"``:
+    a configuration file, or a cell's record, which carries it on."""
+    name = spec.get("family")
+    if not isinstance(name, str) or not name.isidentifier():
+        raise SystemExit(f"{spec.get('name', 'record')}: no model family "
+                         f"(\"family\": <module in bench/reference/>), "
+                         f"got {name!r}")
+    mod_name = f"bench.reference.{name}"
+    try:
+        mod = importlib.import_module(mod_name)
+    except ModuleNotFoundError as e:
+        if e.name != mod_name:
+            raise
+        raise SystemExit(f"family {name!r}: no bench/reference/{name}.py")
+    missing = [k for k in INTERFACE if not hasattr(mod, k)]
+    if missing:
+        raise SystemExit(f"family {name!r}: {mod_name} lacks {missing}")
+    return mod
 
 
 def model_config(cfg_file: dict):
-    """The program's ModelConfig for a configuration file; refuses one
-    whose sizes differ from the published numbers the file holds."""
+    """The program's ModelConfig for a configuration file; its family
+    refuses one whose sizes differ from the published numbers the file
+    holds."""
     from repro.configs.base import get_config
+    fam = family(cfg_file)
     mc = get_config(cfg_file["registry_name"])
     mc = mc.replace(**cfg_file.get("program_overrides", {}))
     if cfg_file.get("check_published", True):
-        bad = {k: (cfg_file[k], getattr(mc, f)) for k, f in PUBLISHED.items()
-               if cfg_file[k] != getattr(mc, f)}
-        if mc.head_dim * mc.num_heads != cfg_file["hidden_size"]:
-            bad["head_dim"] = (cfg_file["hidden_size"], mc.head_dim)
-        if bad:
-            raise SystemExit(f"{mc.name}: differs from its source: {bad}")
+        fam.check_published(cfg_file, mc)
     return mc
 
 
-def dims_of(mc) -> dict:
-    return {"num_layers": mc.num_layers, "d_model": mc.d_model,
-            "num_heads": mc.num_heads, "num_kv_heads": mc.num_kv_heads,
-            "head_dim": mc.head_dim, "d_ff": mc.d_ff,
-            "vocab_size": mc.vocab_size, "rope_theta": mc.rope_theta,
-            "norm_eps": mc.norm_eps}
-
-
-def to_program(w: dict) -> dict:
-    """Benchmark layout -> the program's dense-decoder parameter tree."""
-    return {"embed": {"embedding": w["embed"]},
-            "final_norm": {"scale": w["final_norm"]},
-            "layers": {"run_0": {
-                "ln1": {"scale": w["ln1"]},
-                "attn": {k: w[k] for k in _ATTN},
-                "ln2": {"scale": w["ln2"]},
-                "mlp": {k: w[k] for k in _MLP}}}}
-
-
-def from_program(p: dict) -> dict:
-    """The program's parameter-shaped tree -> benchmark layout."""
-    run = p["layers"]["run_0"]
-    out = {"embed": p["embed"]["embedding"],
-           "final_norm": p["final_norm"]["scale"],
-           "ln1": run["ln1"]["scale"], "ln2": run["ln2"]["scale"]}
-    out.update({k: run["attn"][k] for k in _ATTN})
-    out.update({k: run["mlp"][k] for k in _MLP})
-    return out
-
-
 def check_layout(model, params) -> None:
-    """Raise if the program's parameter tree is not the one ``to_program``
-    builds (a refactor of the model must fail loudly, not misplace)."""
+    """Raise if the program's parameter tree is not the one the family's
+    ``to_program`` builds (a refactor of the model must fail loudly, not
+    misplace)."""
     import jax
     want = jax.eval_shape(model.init, jax.random.key(0))
     got = jax.eval_shape(lambda p: p, params)

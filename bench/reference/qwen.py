@@ -1,17 +1,41 @@
-"""Plain float32 reference of the dense Qwen decoder block (Qwen1.5, Qwen2).
+"""The dense Qwen family (Qwen1.5, Qwen2): everything the benchmark knows
+of it, under the names every family module exports (``bench.program``
+lists them in ``INTERFACE``).
 
-Pre-norm decoder: RMSNorm, attention with QKV bias, rotary embeddings
-(rotate-half, base ``rope_theta``), grouped-query heads, SwiGLU MLP, and
-an output head tied to the embedding.  Written from the published model
-description in straightforward ``jax.numpy``; it imports nothing of the
-system under test.  Every matrix product runs at ``Precision.HIGHEST``.
+- Sizes: ``PUBLISHED`` maps a published ``config.json`` key to the
+  program's ``ModelConfig`` field, ``check_published`` refuses a
+  program whose sizes differ from the file's, ``dims`` gives the sizes
+  the rest of this module takes.
+- Benchmark layout: ``shapes``, ``generate``, ``make_weights``.  Both
+  sides of a comparison make their weights here from ``--seed``: the
+  cells nest them into the program's tree (``to_program``), and the
+  reference makes them again on its own, so it takes nothing the
+  program has made.
+- Program layout: ``to_program``, ``from_program``.
+- Reference: ``train_steps``, ``leaf_norms``, ``logits_at``.
+- Counts: ``train_flops_per_token``, ``serve_flops``, ``attend_cost``.
 
-``prec="fp8"`` is the control: every matrix product's operands are
-rounded to float8 e4m3 (one scale per tensor) before the product, the
-precision step below the bfloat16 the configurations state.
+The block: pre-norm decoder, RMSNorm, attention with QKV bias, rotary
+embeddings (rotate-half, base ``rope_theta``), grouped-query heads,
+SwiGLU MLP, an output head tied to the embedding.  The reference is
+written from the published model description in straightforward
+``jax.numpy``; it imports nothing of the system under test.  Every
+matrix product runs at ``Precision.HIGHEST``; ``prec="fp8"`` is the
+control (``common.mm``), the precision step below the bfloat16 the
+configurations state.  Sequences are processed one at a time, and the
+loss in chunks of positions, so that the full-width models fit next to
+nothing else.
 
-Sequences are processed one at a time, and the loss in chunks of
-positions, so that the full-width models fit next to nothing else.
+Layout (L layers stacked on the leading axis, D model width, H/KV query
+and key-value heads of width hd, F feed-forward width, V vocabulary):
+
+    embed (V, D)  final_norm (D,)  ln1, ln2 (L, D)
+    wq (L, D, H*hd)   bq (L, H*hd)   wk, wv (L, D, KV*hd)   bk, bv (L, KV*hd)
+    wo (L, H*hd, D)   w_gate, w_up (L, D, F)   w_down (L, F, D)
+
+Counts are kept with the benchmark so that no change to the program can
+move the yardstick.  Multiply-adds count as two operations; bf16
+operands are 2 bytes.
 """
 from __future__ import annotations
 
@@ -22,28 +46,120 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-HI = jax.lax.Precision.HIGHEST
-F8_MAX = 448.0          # largest finite float8_e4m3fn
+from bench.reference.common import BF16, mm, rms, seed_key
+
+# ---------------------------------------------------------------- sizes
+
+# published config.json key -> the program's ModelConfig field
+PUBLISHED = {"hidden_size": "d_model", "intermediate_size": "d_ff",
+             "num_attention_heads": "num_heads",
+             "num_key_value_heads": "num_kv_heads",
+             "num_hidden_layers": "num_layers", "vocab_size": "vocab_size",
+             "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+             "tie_word_embeddings": "tie_embeddings"}
 
 
-def _q8(x):
-    """x rounded to float8 e4m3 with one scale per tensor.  The gradient
-    passes straight through the rounding (JAX differentiates a cast to
-    float8 as zero), so the backward pass sees the rounded operands."""
-    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX
-    q = (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
-    return x + jax.lax.stop_gradient(q - x)
+def check_published(cfg_file: dict, mc) -> None:
+    """Refuse a ModelConfig whose sizes differ from the published
+    numbers the configuration file holds."""
+    bad = {k: (cfg_file[k], getattr(mc, f)) for k, f in PUBLISHED.items()
+           if cfg_file[k] != getattr(mc, f)}
+    if mc.head_dim * mc.num_heads != cfg_file["hidden_size"]:
+        bad["head_dim"] = (cfg_file["hidden_size"], mc.head_dim)
+    if bad:
+        raise SystemExit(f"{mc.name}: differs from its source: {bad}")
 
 
-def _mm(spec, a, b, prec):
-    if prec == "fp8":
-        a, b = _q8(a), _q8(b)
-    return jnp.einsum(spec, a, b, precision=HI)
+def dims(mc) -> dict:
+    return {"num_layers": mc.num_layers, "d_model": mc.d_model,
+            "num_heads": mc.num_heads, "num_kv_heads": mc.num_kv_heads,
+            "head_dim": mc.head_dim, "d_ff": mc.d_ff,
+            "vocab_size": mc.vocab_size, "rope_theta": mc.rope_theta,
+            "norm_eps": mc.norm_eps}
 
 
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+# ---------------------------------------------------- benchmark layout
 
+LEAVES = ("embed", "final_norm", "ln1", "ln2", "wq", "bq", "wk", "bk",
+          "wv", "bv", "wo", "w_gate", "w_up", "w_down")
+
+
+def shapes(dims: dict) -> dict:
+    L, D, H, KV, hd, F, V = (dims[k] for k in (
+        "num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
+        "d_ff", "vocab_size"))
+    return {"embed": (V, D), "final_norm": (D,), "ln1": (L, D),
+            "ln2": (L, D), "wq": (L, D, H * hd), "bq": (L, H * hd),
+            "wk": (L, D, KV * hd), "bk": (L, KV * hd),
+            "wv": (L, D, KV * hd), "bv": (L, KV * hd),
+            "wo": (L, H * hd, D), "w_gate": (L, D, F), "w_up": (L, D, F),
+            "w_down": (L, F, D)}
+
+
+def _std(name: str, shape) -> float:
+    if name == "embed":
+        return 0.02
+    if name in ("bq", "bk", "bv"):
+        return 0.02
+    if name in ("final_norm", "ln1", "ln2"):
+        return 0.1
+    return 1.0 / math.sqrt(shape[-2])       # fan-in of a (.., in, out) matrix
+
+
+def generate(dims: dict, key, dtype) -> dict:
+    """The weights for ``key``; call under ``jax.jit``."""
+    out = {}
+    for i, (name, shape) in enumerate(sorted(shapes(dims).items())):
+        x = jax.random.normal(jax.random.fold_in(key, i), shape, dtype)
+        x = x * jnp.asarray(_std(name, shape), dtype)
+        if name in ("final_norm", "ln1", "ln2"):
+            x = x + jnp.ones((), dtype)
+        out[name] = x
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _make(dims_items, key, dtype):
+    return generate(dict(dims_items), key, dtype)
+
+
+def make_weights(dims: dict, seed: int, dtype="bfloat16") -> dict:
+    """All weights in one jitted call on the default device."""
+    items = tuple(sorted((k, int(dims[k])) for k in (
+        "num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
+        "d_ff", "vocab_size")))
+    return _make(items, seed_key(seed), jnp.dtype(dtype))
+
+
+# ------------------------------------------------------ program layout
+
+_ATTN = ("wq", "bq", "wk", "bk", "wv", "bv", "wo")
+_MLP = ("w_gate", "w_up", "w_down")
+
+
+def to_program(w: dict) -> dict:
+    """Benchmark layout -> the program's dense-decoder parameter tree."""
+    return {"embed": {"embedding": w["embed"]},
+            "final_norm": {"scale": w["final_norm"]},
+            "layers": {"run_0": {
+                "ln1": {"scale": w["ln1"]},
+                "attn": {k: w[k] for k in _ATTN},
+                "ln2": {"scale": w["ln2"]},
+                "mlp": {k: w[k] for k in _MLP}}}}
+
+
+def from_program(p: dict) -> dict:
+    """The program's parameter-shaped tree -> benchmark layout."""
+    run = p["layers"]["run_0"]
+    out = {"embed": p["embed"]["embedding"],
+           "final_norm": p["final_norm"]["scale"],
+           "ln1": run["ln1"]["scale"], "ln2": run["ln2"]["scale"]}
+    out.update({k: run["attn"][k] for k in _ATTN})
+    out.update({k: run["mlp"][k] for k in _MLP})
+    return out
+
+
+# ------------------------------------------------------------ reference
 
 def _rope(x, theta):
     """x (S, heads, hd), positions 0..S-1."""
@@ -59,22 +175,22 @@ def _layer(h, lw, dims, prec):
     S = h.shape[0]
     H, KV, hd = dims["num_heads"], dims["num_kv_heads"], dims["head_dim"]
     eps = dims["norm_eps"]
-    x = _rms(h, lw["ln1"], eps)
-    q = (_mm("sd,dk->sk", x, lw["wq"], prec) + lw["bq"]).reshape(S, H, hd)
-    k = (_mm("sd,dk->sk", x, lw["wk"], prec) + lw["bk"]).reshape(S, KV, hd)
-    v = (_mm("sd,dk->sk", x, lw["wv"], prec) + lw["bv"]).reshape(S, KV, hd)
+    x = rms(h, lw["ln1"], eps)
+    q = (mm("sd,dk->sk", x, lw["wq"], prec) + lw["bq"]).reshape(S, H, hd)
+    k = (mm("sd,dk->sk", x, lw["wk"], prec) + lw["bk"]).reshape(S, KV, hd)
+    v = (mm("sd,dk->sk", x, lw["wv"], prec) + lw["bv"]).reshape(S, KV, hd)
     q, k = _rope(q, dims["rope_theta"]), _rope(k, dims["rope_theta"])
     k = jnp.repeat(k, H // KV, axis=1)
     v = jnp.repeat(v, H // KV, axis=1)
-    sc = _mm("qhd,khd->hqk", q, k, prec) / math.sqrt(hd)
+    sc = mm("qhd,khd->hqk", q, k, prec) / math.sqrt(hd)
     causal = jnp.tril(jnp.ones((S, S), bool))
     p = jax.nn.softmax(jnp.where(causal[None], sc, -jnp.inf), axis=-1)
-    o = _mm("hqk,khd->qhd", p, v, prec).reshape(S, H * hd)
-    h = h + _mm("sk,kd->sd", o, lw["wo"], prec)
-    x = _rms(h, lw["ln2"], eps)
-    g = _mm("sd,df->sf", x, lw["w_gate"], prec)
-    u = _mm("sd,df->sf", x, lw["w_up"], prec)
-    return h + _mm("sf,fd->sd", jax.nn.silu(g) * u, lw["w_down"], prec)
+    o = mm("hqk,khd->qhd", p, v, prec).reshape(S, H * hd)
+    h = h + mm("sk,kd->sd", o, lw["wo"], prec)
+    x = rms(h, lw["ln2"], eps)
+    g = mm("sd,df->sf", x, lw["w_gate"], prec)
+    u = mm("sd,df->sf", x, lw["w_up"], prec)
+    return h + mm("sf,fd->sd", jax.nn.silu(g) * u, lw["w_down"], prec)
 
 
 _LAYER_KEYS = ("ln1", "ln2", "wq", "bq", "wk", "bk", "wv", "bv", "wo",
@@ -89,11 +205,11 @@ def hidden(w, tokens, dims, prec="f32", remat=False):
     if remat:
         body = jax.checkpoint(body)
     h, _ = jax.lax.scan(body, h, {k: w[k] for k in _LAYER_KEYS})
-    return _rms(h, w["final_norm"], dims["norm_eps"])
+    return rms(h, w["final_norm"], dims["norm_eps"])
 
 
 def _head(h, w, prec):
-    return _mm("sd,vd->sv", h, w["embed"].astype(jnp.float32), prec)
+    return mm("sd,vd->sv", h, w["embed"].astype(jnp.float32), prec)
 
 
 def _dims_key(dims):
@@ -208,3 +324,69 @@ def train_steps(w, batches, dims, optim, prec="f32", devices=None):
 def leaf_norms(tree):
     return {k: float(jnp.linalg.norm(v.astype(jnp.float32).reshape(-1)))
             for k, v in tree.items()}
+
+
+# --------------------------------------------------------------- counts
+
+def layer_matmul_params(d: dict) -> int:
+    """Weights of one layer's matrix products (q, k, v, o, gate, up, down)."""
+    D, H, KV, hd, F = (d[k] for k in ("d_model", "num_heads", "num_kv_heads",
+                                      "head_dim", "d_ff"))
+    return D * (H + 2 * KV) * hd + H * hd * D + 3 * D * F
+
+
+def layer_params(d: dict) -> int:
+    """All of one layer's weights: matrices, QKV biases, two norm scales."""
+    H, KV, hd, D = (d[k] for k in ("num_heads", "num_kv_heads", "head_dim",
+                                   "d_model"))
+    return layer_matmul_params(d) + (H + 2 * KV) * hd + 2 * D
+
+
+def total_params(d: dict) -> int:
+    """Every parameter; the tied embedding counted once."""
+    return (d["num_layers"] * layer_params(d) + d["d_model"]
+            + d["vocab_size"] * d["d_model"])
+
+
+def train_flops_per_token(d: dict, seq_len: int) -> float:
+    """Forward and backward model operations per token (PaLM, arXiv:
+    2204.02311 App. B): 6N plus the attention term 12*L*H*hd*S, halved
+    because the causal mask leaves half the score matrix to compute.
+    N is ``total_params``: the tied head once, the embedding gather not
+    at all (it is the same matrix).  Recomputation does not count."""
+    attn = 6 * d["num_layers"] * d["num_heads"] * d["head_dim"] * seq_len
+    return 6.0 * total_params(d) + attn
+
+
+def attention_flops(d: dict, context: int) -> float:
+    """Scores and weighted sum for one query over ``context`` keys, all
+    layers."""
+    return 4.0 * d["num_layers"] * d["num_heads"] * d["head_dim"] * context
+
+
+def serve_flops(d: dict, positions: int, context_sum: int,
+                emitted: int) -> float:
+    """Model operations of serving: every processed token (prompt or
+    output) through the layers' matrix products, attention over the live
+    context (``context_sum`` = sum of each token's position + 1), and
+    the output head once per emitted token."""
+    return (2.0 * d["num_layers"] * layer_matmul_params(d) * positions
+            + attention_flops(d, context_sum)
+            + 2.0 * d["vocab_size"] * d["d_model"] * emitted)
+
+
+def kv_bytes_per_token(d: dict) -> int:
+    """K and V of one token across all layers, bf16."""
+    return d["num_layers"] * 2 * d["num_kv_heads"] * d["head_dim"] * BF16
+
+
+def attend_cost(d: dict, context_sum: int, rows: int, kv_positions: int):
+    """(operations, bytes) that an attention kernel of one query row per
+    token needs, summed over all layers: ``rows`` query rows whose
+    contexts add up to ``context_sum`` positions, over the K/V of
+    ``kv_positions`` positions (each request's K/V up to its furthest
+    row, read once), plus the queries and the outputs."""
+    L, H, hd = d["num_layers"], d["num_heads"], d["head_dim"]
+    flops = attention_flops(d, context_sum)
+    q_and_out = 2 * rows * L * H * hd * BF16
+    return flops, kv_bytes_per_token(d) * kv_positions + q_and_out

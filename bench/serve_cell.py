@@ -13,11 +13,11 @@ The engine then stops and hands back what it had not finished with the
 tokens it had served (``Engine.reclaim_requests``); that work is neither
 failed nor counted.
 
-After the window the engine is freed and ``bench/reference`` recomputes,
-in float32, the logits at every served position of a sample of the
-requests that were served tokens, drawn from the seed, the longest among
-them; a served token's gap is how far its reference logit lies below the
-reference's best.
+After the window the engine is freed and the family's reference
+(``bench.program.family``) recomputes, in float32, the logits at every
+served position of a sample of the requests that were served tokens,
+drawn from the seed, the longest among them; a served token's gap is how
+far its reference logit lies below the reference's best.
 
 Faults (tests and ``bench/calibrate.py``): ``alter_token`` changes the
 last served token of the first request as the engine hands it over;
@@ -34,8 +34,6 @@ import time
 import numpy as np
 
 from bench import program, traffic
-from bench.reference import qwen as ref
-from bench.reference import weights
 
 SAMPLE = 16              # requests the reference recomputes
 TRACE_S = 10.0           # length of the profiled stretch
@@ -66,11 +64,11 @@ def build(cfg, seed, model=None, phases=None):
     from repro.serve import Engine, EngineConfig
     phases = {} if phases is None else phases
     t = time.perf_counter()
+    fam = program.family(cfg)
     mc = program.model_config(cfg)
     model = model or build_model(mc)
-    dims = program.dims_of(mc)
-    params = program.to_program(
-        weights.make_weights(dims, seed, mc.param_dtype))
+    params = fam.to_program(
+        fam.make_weights(fam.dims(mc), seed, mc.param_dtype))
     program.check_layout(model, params)
     phases["weights_s"] = time.perf_counter() - t
     t = time.perf_counter()
@@ -189,8 +187,9 @@ def _tokens_at(marks, t):
 
 def run(*, cfg, mix, seed, seconds, tracer, devs, t_start, faults):
     from bench.harness import memory_peak
+    fam = program.family(cfg)
     mc = program.model_config(cfg)
-    dims = program.dims_of(mc)
+    dims = fam.dims(mc)
     phases = {}
     engine = build(cfg, seed, phases=phases)
     max_len = engine.cfg.max_seq_len
@@ -210,8 +209,8 @@ def run(*, cfg, mix, seed, seconds, tracer, devs, t_start, faults):
     due = sum(1 for d in plan if d.due_s <= window_s)
     failed = sum(1 for r in out["results"].values() if r.fault is not None)
     sample = _sample(served, by_rid, seed)
-    gaps = _gaps(dims, seed, mc.param_dtype, sample, by_rid, served, max_len,
-                 "fp8" if control else None)
+    gaps = _gaps(fam, dims, seed, mc.param_dtype, sample, by_rid, served,
+                 max_len, "fp8" if control else None)
     c0, c1 = out["c0"], out["c1"]
     late = out["late"]
     rec = {"kind": "serve", "setup_s": out["setup_s"], "window_s": window_s,
@@ -222,7 +221,7 @@ def run(*, cfg, mix, seed, seconds, tracer, devs, t_start, faults):
                                         - c0["decode_active_slot_steps"]),
            "sound": bool(sample),
            "checks": {"logit_gap": gaps["control" if control else "program"]},
-           "memory_peak": memory, "dims": dims,
+           "memory_peak": memory, "family": cfg["family"], "dims": dims,
            "info": {"requests_due": len(plan), "due_in_window": due,
                     "finished": len(out["results"]), "failed": failed,
                     "served_requests": len(served),
@@ -240,7 +239,7 @@ def run(*, cfg, mix, seed, seconds, tracer, devs, t_start, faults):
                     "logit_gap_program": gaps["program"],
                     "memory_peak_bytes": memory}}
     if tracer.enabled:
-        _reduce(rec, tracer.reduce(), log, dims, *out["traced"])
+        _reduce(rec, tracer.reduce(), log, fam, dims, *out["traced"])
     return rec
 
 
@@ -257,11 +256,12 @@ def _sample(served, by_rid, seed):
     return [longest] + sorted(rest[i] for i in pick)
 
 
-def reference_gaps(w32, dims, prompt, served, max_len, prec="f32"):
-    """Per served token: the reference's best logit minus its logit for
-    the served token; with ``prec`` other than f32, also the gap of the
-    token that precision ranks first.  Positions are padded to
-    ``max_len`` so that one compiled program serves every request."""
+def reference_gaps(fam, w32, dims, prompt, served, max_len, prec="f32"):
+    """Per served token: the best logit of the family ``fam``'s
+    reference minus its logit for the served token; with ``prec`` other
+    than f32, also the gap of the token that precision ranks first.
+    Positions are padded to ``max_len`` so that one compiled program
+    serves every request."""
     import jax.numpy as jnp
     seq = np.concatenate([prompt, np.asarray(served[:-1], np.int32)])
     n = len(served)
@@ -269,26 +269,27 @@ def reference_gaps(w32, dims, prompt, served, max_len, prec="f32"):
     toks[:len(seq)] = seq
     idx = np.full(max_len, len(prompt) - 1, np.int32)
     idx[:n] = len(prompt) - 1 + np.arange(n)
-    lg = np.asarray(ref.logits_at(w32, jnp.asarray(toks), jnp.asarray(idx),
+    lg = np.asarray(fam.logits_at(w32, jnp.asarray(toks), jnp.asarray(idx),
                                   dims))[:n]
     best = lg.max(-1)
     own = best - lg[np.arange(n), np.asarray(served)]
     if prec == "f32":
         return own, None
-    lo = np.asarray(ref.logits_at(w32, jnp.asarray(toks), jnp.asarray(idx),
+    lo = np.asarray(fam.logits_at(w32, jnp.asarray(toks), jnp.asarray(idx),
                                   dims, prec))[:n]
     return own, best - lg[np.arange(n), lo.argmax(-1)]
 
 
-def _gaps(dims, seed, dtype, sample, by_rid, served, max_len, control=None):
+def _gaps(fam, dims, seed, dtype, sample, by_rid, served, max_len,
+          control=None):
     import jax.numpy as jnp
-    w = weights.make_weights(dims, seed, dtype)
+    w = fam.make_weights(dims, seed, dtype)
     w32 = {k: v.astype(jnp.float32) for k, v in w.items()}
     del w
     prog, ctrl = [], []
     for rid in sample:
-        own, lo = reference_gaps(w32, dims, by_rid[rid].prompt, served[rid],
-                                 max_len, control or "f32")
+        own, lo = reference_gaps(fam, w32, dims, by_rid[rid].prompt,
+                                 served[rid], max_len, control or "f32")
         prog.append(float(own.max()))
         if lo is not None:
             ctrl.append(float(lo.max()))
@@ -297,8 +298,7 @@ def _gaps(dims, seed, dtype, sample, by_rid, served, max_len, control=None):
             "control": max(ctrl) if ctrl else float("inf")}
 
 
-def _reduce(rec, trace, log, dims, t_tr0, t_tr1):
-    from bench import flops
+def _reduce(rec, trace, log, fam, dims, t_tr0, t_tr1):
     from bench import trace_reduce as tr
     w0, w1 = tr.window_of(trace)
     busy = tr.busy(trace, w0, w1)
@@ -313,7 +313,7 @@ def _reduce(rec, trace, log, dims, t_tr0, t_tr1):
             context += int((valid * pos + valid * (valid + 1) // 2).sum())
             emitted += int(((valid > 0) & (dst >= 0)).sum())
             if valid.max() <= 1:
-                paged.append(_paged_cost(dims, pos, valid, rid))
+                paged.append(_paged_cost(fam, dims, pos, valid, rid))
         else:
             pos0, steps = meta[0], meta[1]
             positions += int(steps.sum())
@@ -323,24 +323,24 @@ def _reduce(rec, trace, log, dims, t_tr0, t_tr1):
     rec.update({
         "traced_s": (w1 - w0) * 1e-9,
         "busy_s": tr.mean(busy) * 1e-9,
-        "traced_flops": flops.serve_flops(dims, positions, context, emitted),
+        "traced_flops": fam.serve_flops(dims, positions, context, emitted),
         "paged_costs": paged,
         "paged_executions": n_exec, "paged_s": paged_ns * 1e-9,
         "breakdown": {"device_ops": tr.top_ops(trace, w0, w1),
                       "idle_gaps": tr.idle_gaps(trace, w0, w1)}})
 
 
-def _paged_cost(dims, pos, valid, rid):
+def _paged_cost(fam, dims, pos, valid, rid):
     """(operations, bytes) that ``flash_decode_paged`` needs in one step
-    of one row per token: each row's query against the K/V up to its
-    own position, the K/V of a request read once."""
-    from bench import flops
+    of one row per token, by the family ``fam``'s count: each row's
+    query against the K/V up to its own position, the K/V of a request
+    read once."""
     live = valid > 0
     ctx = {}
     for r, p in zip(rid[live].tolist(), pos[live].tolist()):
         ctx[r] = max(ctx.get(r, 0), p + 1)
-    return flops.attend_cost(dims, int((pos[live] + 1).sum()),
-                             int(live.sum()), sum(ctx.values()))
+    return fam.attend_cost(dims, int((pos[live] + 1).sum()),
+                           int(live.sum()), sum(ctx.values()))
 
 
 # flash_decode_paged in the device trace.  Pallas calls carry no name of

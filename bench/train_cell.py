@@ -5,8 +5,9 @@ Set-up makes the state on the device from the seed (the benchmark's
 weights in the program's tree), compiles the step and drives it through
 its first three steps, reading what the correctness check needs; the
 window then runs that same compiled step on that same state.  After the
-window the state is freed and ``bench/reference`` repeats the three
-steps in float32 on the same batches.
+window the state is freed and the reference repeats the three steps in
+float32 on the same batches.  Layouts, reference and counts come from
+the family module the configuration names (``bench.program.family``).
 
 Compared, each against its limit (``bench/limits/<cell>.json``):
 
@@ -37,14 +38,14 @@ the precision step below the configurations' bf16.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import time
 
 import numpy as np
 
 from bench import program
-from bench.reference import qwen as ref
-from bench.reference import weights
+from bench.reference.common import seed_key
 
 SETUP_STEPS = 3          # steps the reference follows
 TRACE_STEPS = 3          # steps in the profiled stretch
@@ -71,10 +72,10 @@ def _half_batch(step):
 FAULTS = {"frozen": _frozen, "half_batch": _half_batch}
 
 
-def _norms(tree):
+def _norms(from_program, tree):
     import jax.numpy as jnp
     return {k: jnp.linalg.norm(v.astype(jnp.float32).reshape(-1))
-            for k, v in program.from_program(tree).items()}
+            for k, v in from_program(tree).items()}
 
 
 def prepare(cfg, mix, seed, devs, faults):
@@ -90,9 +91,11 @@ def prepare(cfg, mix, seed, devs, faults):
     from repro.models.model import build_model
     from repro.optim.sgd import OptimConfig
 
+    fam = program.family(cfg)
     mc = program.model_config(cfg)
     model = build_model(mc)
-    dims = program.dims_of(mc)
+    dims = fam.dims(mc)
+    norms = functools.partial(_norms, fam.from_program)
     opt = cfg["optimizer"]
     shape = tuple(mix["mesh"])
     mesh = make_mesh(shape, ("pod", "data", "model")[-len(shape):])
@@ -109,13 +112,13 @@ def prepare(cfg, mix, seed, devs, faults):
 
     def build(key):
         st = init(jax.random.key(0))   # its random params are dead code
-        st["params"] = program.to_program(
-            weights.generate(dims, key, jnp.dtype(mc.param_dtype)))
+        st["params"] = fam.to_program(
+            fam.generate(dims, key, jnp.dtype(mc.param_dtype)))
         return st
 
     phases = {}
     t = time.perf_counter()
-    state = jax.block_until_ready(jax.jit(build)(weights.seed_key(seed)))
+    state = jax.block_until_ready(jax.jit(build)(seed_key(seed)))
     phases["state_s"] = time.perf_counter() - t
     program.check_layout(model, state["params"])
     if dp > 1:
@@ -150,7 +153,7 @@ def prepare(cfg, mix, seed, devs, faults):
         @jax.jit
         def change(st, w0):
             fin = finalize(st)["params"]
-            return _norms(jax.tree.map(
+            return norms(jax.tree.map(
                 lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
                 fin, w0))
 
@@ -162,9 +165,9 @@ def prepare(cfg, mix, seed, devs, faults):
             if i == 0:
                 # read before the next step donates the state
                 g = first_grad(state, w0)
-                g_norms = jax.jit(_norms)(g)
+                g_norms = jax.jit(norms)(g)
                 g = {k: np.asarray(v, np.float32) for k, v in
-                     program.from_program(jax.device_get(g)).items()}
+                     fam.from_program(jax.device_get(g)).items()}
         d = change(state, w0)
         del w0
         readings = {"losses": [float(x) for x in losses],
@@ -175,22 +178,23 @@ def prepare(cfg, mix, seed, devs, faults):
     except BaseException:
         loader.close()
         raise
-    return {"mc": mc, "dims": dims, "opt": opt, "state": state,
+    return {"fam": fam, "mc": mc, "dims": dims, "opt": opt, "state": state,
             "step": step, "feed": feed, "loader": loader,
             "batches": [b["tokens"] for b in first], "readings": readings,
             "tokens_per_step": rows * seq, "seq_len": seq,
             "phases": phases}
 
 
-def reference(dims, seed, dtype, batches, opt, prec="f32", devices=None):
-    """The reference's readings: losses of the three steps, per-leaf
-    norms of the first gradient and of the change after three steps.
-    ``devices`` share the sequences of a step."""
-    w = weights.make_weights(dims, seed, dtype)
-    losses, g, w3 = ref.train_steps(w, batches, dims, opt, prec, devices)
-    out = {"losses": losses, "grad": ref.leaf_norms(g),
+def reference(fam, dims, seed, dtype, batches, opt, prec="f32",
+              devices=None):
+    """The family ``fam``'s reference readings: losses of the three
+    steps, per-leaf norms of the first gradient and of the change after
+    three steps.  ``devices`` share the sequences of a step."""
+    w = fam.make_weights(dims, seed, dtype)
+    losses, g, w3 = fam.train_steps(w, batches, dims, opt, prec, devices)
+    out = {"losses": losses, "grad": fam.leaf_norms(g),
            "grad_tree": {k: np.asarray(v, np.float32) for k, v in g.items()},
-           "change": ref.leaf_norms({k: w3[k].astype("float32")
+           "change": fam.leaf_norms({k: w3[k].astype("float32")
                                      - w[k].astype("float32") for k in w})}
     del w, g, w3
     gc.collect()
@@ -282,8 +286,8 @@ def run(*, cfg, mix, seed, seconds, tracer, devs, t_start, faults):
     del state, step, pre["state"], pre["step"]
     gc.collect()
 
-    args = (pre["dims"], seed, pre["mc"].param_dtype, pre["batches"],
-            pre["opt"])
+    args = (pre["fam"], pre["dims"], seed, pre["mc"].param_dtype,
+            pre["batches"], pre["opt"])
     r = reference(*args, devices=devs)
     p = pre["readings"]
     program_gaps = gaps(p, r)
@@ -298,7 +302,8 @@ def run(*, cfg, mix, seed, seconds, tracer, devs, t_start, faults):
            "attempted": n,
            "failed": int(sum(not np.isfinite(x) for x in win_losses)),
            "sound": bool(keep), "checks": gaps(p, r), "memory_peak": memory,
-           "input_wait_s": wait, "dims": pre["dims"],
+           "input_wait_s": wait, "family": cfg["family"],
+           "dims": pre["dims"],
            "seq_len": pre["seq_len"],
            "info": {"losses_program": p["losses"],
                     "losses_reference": r["losses"],

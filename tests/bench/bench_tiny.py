@@ -23,7 +23,9 @@ TINY_LIMITS = {"loss1_gap": 0.0015, "loss_gap": 0.002, "grad_gap": 0.008,
                "grad_diff": 0.06, "change_gap": 0.012, "logit_gap": 0.05}
 
 
-def overrides(cell, faults=None, limits=None):
+def overrides(cell, faults=None, limits=None, config=None, traffic=None):
+    """The smoke-size overrides of ``cell``; ``config`` and ``traffic``
+    add to (and win over) the configuration's and the mix's."""
     _, _, cfg, mix, _ = harness.load_cell(cell)
     po = dict(cfg["program_overrides"])
     po.update(num_layers=2, d_model=128, d_ff=256, vocab_size=512)
@@ -45,6 +47,8 @@ def overrides(cell, faults=None, limits=None):
                                     "max": 150},
                          "output": {"median": 12, "sigma": 0.8, "min": 2,
                                     "max": 64}}
+    ov["config"].update(config or {})
+    ov["traffic"].update(traffic or {})
     return ov
 
 

@@ -49,9 +49,8 @@ def test_compile_inside_window_ends_the_run():
     from repro.models.model import build_model
     from repro.serve import Engine, EngineConfig
     mc = program.model_config(cfg)
-    from bench.reference import weights
-    params = program.to_program(
-        weights.make_weights(program.dims_of(mc), 1, mc.param_dtype))
+    fam = program.family(cfg)
+    params = fam.to_program(fam.make_weights(fam.dims(mc), 1, mc.param_dtype))
     engine = Engine(build_model(mc), params, EngineConfig(**cfg["engine"]))
     plan = traffic.schedule(mix, 1, 1.0, mc.vocab_size)     # no warmup()
     with pytest.raises(SystemExit, match="compiles inside the window"):

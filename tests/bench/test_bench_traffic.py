@@ -10,10 +10,12 @@ import numpy as np
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import flops, traffic  # noqa: E402
+from bench import program, traffic  # noqa: E402
 
 MIX = json.loads((ROOT / "bench" / "traffic" / "chat-overload.json")
                  .read_text())
+FAMILY = program.family(json.loads(
+    (ROOT / "bench" / "configs" / "qwen2-1.5b.json").read_text()))
 QWEN2 = {"num_layers": 28, "d_model": 1536, "num_heads": 12,
          "num_kv_heads": 2, "head_dim": 128, "d_ff": 8960,
          "vocab_size": 151936}
@@ -71,6 +73,6 @@ def test_paged_cost_reads_each_request_once():
     pos = np.array([0, 1, 2, 3, 10, 0])
     valid = np.array([1, 1, 1, 1, 1, 0])
     rid = np.array([7, 7, 7, 7, 9, 0])
-    ops, nbytes = serve_cell._paged_cost(QWEN2, pos, valid, rid)
-    assert (ops, nbytes) == flops.attend_cost(QWEN2, 1 + 2 + 3 + 4 + 11, 5,
-                                              4 + 11)
+    ops, nbytes = serve_cell._paged_cost(FAMILY, QWEN2, pos, valid, rid)
+    assert (ops, nbytes) == FAMILY.attend_cost(QWEN2, 1 + 2 + 3 + 4 + 11, 5,
+                                               4 + 11)
